@@ -46,6 +46,7 @@ from ..ops.mergetree_kernel import (
     raise_kernel_errors,
 )
 from ..ops.overlay_pallas import (
+    OVERLAY_PROGRAM,
     REC_DROP_SPAN,
     REC_NONE,
     REC_SETTLE_SPAN,
@@ -62,6 +63,7 @@ from ..ops.overlay_ref import (
     merge_span_props,
 )
 from ..testing.synthetic import ColumnarStream
+from ..utils.metrics import named_program, span
 
 
 def reconstruct_settled(
@@ -167,6 +169,7 @@ def _stream_step_fn(B: int, interpret: bool, n_ops_seg: int,
     executable."""
 
     @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+    @named_program(OVERLAY_PROGRAM + "_segment")
     def step(table, log, counts, dev, epoch0):
         offs = [0]
         for k in shapes:
@@ -220,12 +223,19 @@ class OverlayDeviceReplica:
         # Every row ever created folds (or survives) exactly once; ~3
         # rows/op (insert + split tails / gap spans) bounds the log.
         self.log_cap = log_cap or (3 * n + 4 * window)
-        self.table = make_overlay_table(
-            window, n_removers, n_prop_keys, settled_len=initial_len
-        )
-        self.log = jnp.zeros((self.log_cap, 5 + n_prop_keys), jnp.int32)
-        self.counts = jnp.zeros(max(self.n_chunks, 1), jnp.int32)
-        self.cursor = jnp.int32(0)
+        # The feed's layer spans: device allocations here, padding
+        # (`prepare_host`), the upload (`prepare`) or per-segment puts.
+        alloc, self._sp_pad, self._sp_upload, self._sp_put = (
+            span(f"overlay.{phase}")
+            for phase in ("alloc", "pad", "upload", "segment_put"))
+        with alloc:
+            self.table = make_overlay_table(
+                window, n_removers, n_prop_keys, settled_len=initial_len
+            )
+            self.log = jnp.zeros((self.log_cap, 5 + n_prop_keys),
+                                 jnp.int32)
+            self.counts = jnp.zeros(max(self.n_chunks, 1), jnp.int32)
+            self.cursor = jnp.int32(0)
         self.chunks_done = 0
         self._doc: Optional[OverlayDoc] = None
         self._dev: Optional[OpBatch] = None
@@ -241,8 +251,9 @@ class OverlayDeviceReplica:
         if getattr(self, "_dev", None) is not None:
             return
         self.prepare_host()
-        self._dev = OpBatch(*(jnp.asarray(a) for a in self._host))
-        self._msn_by_chunk = jnp.asarray(self._host_msn)
+        with self._sp_upload:
+            self._dev = OpBatch(*(jnp.asarray(a) for a in self._host))
+            self._msn_by_chunk = jnp.asarray(self._host_msn)
 
     def prepare_host(self) -> None:
         """Decode the stream into padded HOST arrays only (the
@@ -250,6 +261,10 @@ class OverlayDeviceReplica:
         replay itself feeds segments in)."""
         if getattr(self, "_host", None) is not None:
             return
+        with self._sp_pad:
+            self._pad_host()
+
+    def _pad_host(self) -> None:
         s = self.stream
         n = len(s)
         B = self.chunk_size
@@ -299,7 +314,9 @@ class OverlayDeviceReplica:
                  for a in self._host]
                 + [self._host_msn[lo_c:hi_c]]
             ).astype(np.int32)
-            return lo_c, hi - lo, hi_c - lo_c, jax.device_put(packed)
+            with self._sp_put:
+                dev = jax.device_put(packed)
+            return lo_c, hi - lo, hi_c - lo_c, dev
 
         shapes = tuple(
             (a.shape[1] if a.ndim > 1 else 0) for a in self._host

@@ -50,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..protocol.constants import NO_CLIENT
+from ..utils.metrics import named_program
 from .mergetree_kernel import (
     ERR_BAD_POS,
     ERR_CAPACITY,
@@ -619,6 +620,13 @@ def _to_tiles(v):
     return v.reshape(-1, LANES)
 
 
+# The XLA module name of the fused replay (``jit_replay_fused``; the
+# streaming path's per-segment program extends it) and the name of its
+# Pallas chunk kernel: profiler readers find device time by these.
+OVERLAY_PROGRAM = "replay_fused"
+OVERLAY_KERNEL = "overlay_apply_chunk"
+
+
 @functools.partial(jax.jit, static_argnums=(2,))
 def overlay_apply_chunk(table: OverlayTable, ops: OpBatch,
                         interpret: bool = False) -> OverlayTable:
@@ -667,6 +675,7 @@ def overlay_apply_chunk(table: OverlayTable, ops: OpBatch,
     outs = pl.pallas_call(
         _overlay_chunk_kernel,
         out_shape=out_shapes,
+        name=OVERLAY_KERNEL,
         in_specs=[smem()] * 14 + [vmem()] * 8,
         out_specs=tuple([vmem()] * 8 + [smem(), smem()]),
         scratch_shapes=[
@@ -791,6 +800,7 @@ def fold_device(table: OverlayTable, msn: jnp.ndarray):
 @functools.partial(
     jax.jit, static_argnums=(5, 6), donate_argnums=(0, 2, 3)
 )
+@named_program(OVERLAY_PROGRAM)
 def replay_fused(
     table: OverlayTable, stream_ops: OpBatch, log, counts, msn_by_chunk,
     chunk: int, interpret: bool = False, epoch0=0,

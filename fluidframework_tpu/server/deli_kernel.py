@@ -71,7 +71,7 @@ from ..protocol.messages import (
     SequencedMessage,
     trace_submit_ts,
 )
-from ..utils.metrics import get_registry
+from ..utils.metrics import get_registry, span
 from .log import LogConsumer, MessageLog
 from .sequencer import (
     NACK_FUTURE_REFSEQ,
@@ -681,8 +681,12 @@ class PackedDeliCore:
 
     def __init__(self, n_docs: int = 8, n_clients: int = 8,
                  max_resident: Optional[int] = None, max_cols: int = 256,
-                 dedup: bool = False, mesh=None):
+                 dedup: bool = False, mesh=None, kernel_span=None):
+        """`kernel_span` (a `utils.metrics.Span`) times each sequencer
+        call through its verdict fetch; the role passes its own
+        ``<role>.kernel``."""
         self.pool = SeqPool(n_docs, n_clients, max_resident, mesh=mesh)
+        self._sp_kernel = kernel_span or span("deli.kernel")
         self.max_cols = max(8, max_cols)
         self.dedup = dedup
         # Submissions accumulate as ORDERED segments: lists of
@@ -697,9 +701,9 @@ class PackedDeliCore:
         self._n_subs = 0
         self._gctr: Dict[int, int] = {}
         # Kernel-path instrumentation: one histogram observation + a
-        # handful of gauge/counter updates PER PUMP (never per record —
-        # the config-5 overhead guard in tools/bench_configs.py holds
-        # the cost under 5%).
+        # handful of gauge/counter updates PER PUMP (never per record:
+        # the benchmark's untraced runs, whose end-to-end metrics carry
+        # this cost, are what a regression shows in).
         m = get_registry()
         self._m_pump = m.histogram(
             "deli_pump_records",
@@ -813,9 +817,11 @@ class PackedDeliCore:
                     cols6[:, 1], cols6[:, 2], cols6[:, 3],
                     cols6[:, 4], cols6[:, 5], pool.n_docs, self.max_cols,
                 ):
-            res, aborted = pool.run_chunk(
-                kind, client, cseq, ref, grp, self.dedup, aborted
-            )
+            with self._sp_kernel as k:
+                k.n = len(sl)
+                res, aborted = pool.run_chunk(
+                    kind, client, cseq, ref, grp, self.dedup, aborted
+                )
             seq_o[sel] = res.seq[sl, ic]
             msn_o[sel] = res.min_seq[sl, ic]
             nack_o[sel] = res.nack[sl, ic]
@@ -1175,7 +1181,11 @@ class KernelDeliRole(_Role):
                 device_plane, plane_column,
                 partition_key=self.partition, env=True,
             )
-        self.core = PackedDeliCore(dedup=True, mesh=self.mesh)
+        labels = self._metric_labels()
+        self._sp_kernel = span(f"{self.span_prefix}.kernel", **labels)
+        self._sp_emit = span(f"{self.span_prefix}.emit", **labels)
+        self.core = PackedDeliCore(dedup=True, mesh=self.mesh,
+                                   kernel_span=self._sp_kernel)
         self._pending: List[tuple] = []  # ("rec", off, dict) |
         #                                 ("cols", start_off, RecordBatch)
         # Blob pass-through is only legal when the output topic can
@@ -1196,7 +1206,8 @@ class KernelDeliRole(_Role):
     def restore_state(self, state: Any) -> None:
         from .supervisor import unwrap_ranged_state
 
-        core = PackedDeliCore(dedup=True, mesh=self.mesh)
+        core = PackedDeliCore(dedup=True, mesh=self.mesh,
+                              kernel_span=self._sp_kernel)
         core.pool.restore_docs(unwrap_ranged_state(state))
         self.core = core
 
@@ -1312,10 +1323,11 @@ class KernelDeliRole(_Role):
                             plan_record, plan_boxcar, passthrough)
         self._pending = []
         res = core.run(as_arrays=emit_cols)
-        if emit_cols:
-            self._emit_columns(plan, res, out)
-        else:
-            self._emit_dicts(plan, res, out)
+        with self._sp_emit:
+            if emit_cols:
+                self._emit_columns(plan, res, out)
+            else:
+                self._emit_dicts(plan, res, out)
 
     # ------------------------------------------------- columnar ingest
 

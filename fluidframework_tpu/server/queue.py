@@ -33,6 +33,8 @@ import time
 from typing import Any, Callable, Dict, Iterator, List, Optional, \
     Protocol, Tuple
 
+from ..utils.metrics import span
+
 
 class FencedError(RuntimeError):
     """A write carried a fencing token older than (or tied with but not
@@ -927,9 +929,20 @@ class FencedCheckpointStore:
     def __init__(self, directory: str):
         self.dir = directory
         os.makedirs(directory, exist_ok=True)
+        self._phase_spans: Dict[str, tuple] = {}
 
     def _path(self, key: str) -> str:
         return os.path.join(self.dir, f"{key}.ckpt.json")
+
+    def _spans(self, key: str) -> tuple:
+        """The save's phase spans (fence, encode, write) for `key`,
+        built on its first save."""
+        sp = self._phase_spans.get(key)
+        if sp is None:
+            sp = self._phase_spans[key] = tuple(
+                span(f"checkpoint.{phase}", role=key)
+                for phase in ("fence", "encode", "write"))
+        return sp
 
     def load(self, key: str) -> Optional[dict]:
         """The checkpoint envelope ({"fence", "owner", "state"}) or
@@ -948,8 +961,11 @@ class FencedCheckpointStore:
         (the checkpoint-bytes metric's source)."""
         import fcntl
 
+        sp_fence, sp_encode, sp_write = self._spans(key)
         lock_path = self._path(key) + ".lock"
-        with open(lock_path, "a+") as lk:
+        with open(lock_path, "a+") as lk, contextlib.ExitStack() as gate:
+            # The fence phase: lock wait, previous envelope, fence check.
+            gate.enter_context(sp_fence)
             if lock_timeout_s is None:
                 fcntl.flock(lk.fileno(), fcntl.LOCK_EX)
             else:
@@ -974,16 +990,20 @@ class FencedCheckpointStore:
                         int(cur.get("fence", 0)), cur.get("owner"),
                         fence, owner, f"checkpoint {key!r}",
                     )
+                del cur  # freeing the previous envelope is fence work too
+                gate.close()
                 tmp = self._path(key) + f".tmp.{os.getpid()}"
-                payload = json.dumps(
-                    {"fence": fence, "owner": owner, "state": state}
-                )
-                check_disk_fault("checkpoint")
-                with open(tmp, "w") as f:
-                    f.write(payload)
-                    f.flush()
-                    fsync_file(f, "checkpoint")
-                os.replace(tmp, self._path(key))
+                with sp_encode:
+                    payload = json.dumps(
+                        {"fence": fence, "owner": owner, "state": state}
+                    )
+                with sp_write:
+                    check_disk_fault("checkpoint")
+                    with open(tmp, "w") as f:
+                        f.write(payload)
+                        f.flush()
+                        fsync_file(f, "checkpoint")
+                    os.replace(tmp, self._path(key))
             finally:
                 fcntl.flock(lk.fileno(), fcntl.LOCK_UN)
         return len(payload)

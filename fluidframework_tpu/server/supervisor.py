@@ -301,16 +301,11 @@ class _Role:
         self._ckpt_last_s = 0.0
         self._ckpt_pending_bytes = 0
         self._hb_t = 0.0
-        from ..utils.metrics import get_registry
+        from ..utils.metrics import get_registry, span
 
         self.metrics = get_registry()
         m = self.metrics
         labels = self._metric_labels()
-        self._m_pump = m.histogram(
-            "role_pump_records",
-            buckets=(1, 4, 16, 64, 256, 1024, 4096, 16384),
-            **labels,
-        )
         self._m_records = m.counter("role_records_total", **labels)
         self._m_ckpt_writes = m.counter(
             "checkpoint_writes_total", **labels
@@ -322,6 +317,15 @@ class _Role:
         self._m_fenced = m.counter("fence_rejections_total", **labels)
         self._m_disk_retries = m.counter("disk_retries_total", **labels)
         self._m_degraded = m.gauge("role_degraded", **labels)
+        # The step's layer spans, `<role>.<phase>` (`utils.metrics.span`):
+        # the pump is the root of every step that reaches the poll.
+        self.span_prefix = self.role_base or self.name
+        (self._sp_pump, self._sp_poll, self._sp_flush, self._sp_append,
+         self._sp_ckpt, self._sp_snapshot, self._sp_heartbeat,
+         self._sp_idle) = (
+            span(f"{self.span_prefix}.{phase}", **labels)
+            for phase in ("pump", "poll", "flush", "append", "checkpoint",
+                          "checkpoint.snapshot", "heartbeat", "idle"))
 
     # ------------------------------------------------------------ state
 
@@ -434,6 +438,10 @@ class _Role:
                 and now - self._hb_t < self.hb_interval_s):
             return
         self._hb_t = now
+        with self._sp_heartbeat:
+            self._write_heartbeat()
+
+    def _write_heartbeat(self) -> None:
         tmp = self._hb_path + f".tmp.{os.getpid()}"
         hb = {
             "pid": os.getpid(), "owner": self.owner, "t": time.time(),
@@ -461,7 +469,9 @@ class _Role:
             if spans:
                 hb["slow_ops"] = spans
         with open(tmp, "w") as f:
-            json.dump(hb, f)
+            # One string from the C encoder: `json.dump` streams through
+            # the pure-Python one, several times slower per instrument.
+            f.write(json.dumps(hb))
         os.replace(tmp, self._hb_path)
 
     def _durable(self, fn):
@@ -585,15 +595,19 @@ class _Role:
         return done
 
     def checkpoint(self) -> None:
-        t0 = time.perf_counter()
-        n_bytes = self._durable(lambda: self.ckpt.save(
-            self.name,
-            {"offset": self.offset, "state": self.snapshot_state()},
-            fence=self.fence, owner=self.owner,
-        ))
+        with self._sp_ckpt:
+            t0 = time.perf_counter()
+            with self._sp_snapshot:
+                state = {"offset": self.offset,
+                         "state": self.snapshot_state()}
+            n_bytes = self._durable(lambda: self.ckpt.save(
+                self.name, state, fence=self.fence, owner=self.owner,
+            ))
+            with self._sp_snapshot:
+                del state  # freeing the snapshot's dicts is its cost too
+            self._ckpt_last_s = time.perf_counter() - t0
         self._m_ckpt_writes.inc()
         self._m_ckpt_bytes.inc(n_bytes)
-        self._ckpt_last_s = time.perf_counter() - t0
         self._m_ckpt_ms.observe(self._ckpt_last_s * 1000.0)
         self._ckpt_dirty = False
         self._ckpt_pending_bytes = 0
@@ -638,6 +652,16 @@ class _Role:
             self._recover()
         else:
             self._renew_or_die(now)
+        with self._sp_pump as pump:
+            pump.n = moved = self._pump()
+        if not moved:
+            with self._sp_idle:
+                self._idle_wait(idle_sleep)
+        return moved
+
+    def _pump(self) -> int:
+        """One input batch: poll, process, flush, fenced append,
+        checkpoint cadence, heartbeat. Returns records moved."""
         # Micro-batch cap (threaded into the read): a deep input
         # backlog yields between steps, so lease renewal + heartbeat
         # stay live no matter how far behind the role is. The tail is
@@ -650,22 +674,25 @@ class _Role:
         self._in_pos = getattr(self._reader, "_pos", None)
         out: List[dict] = []
         moved = 0
-        if self.ingest_batches and hasattr(self._reader, "poll_batches"):
-            # Columnar zero-decode path: whole RecordBatch frames go to
-            # process_batch; stray decoded records (a migrated JSONL
-            # history) take the per-record path.
-            for unit in self._reader.poll_batches(self.batch):
-                if unit[0] == "batch":
-                    moved += unit[2].n
-                    self.process_batch(unit[1], unit[2], out)
-                else:
-                    moved += 1
-                    self.process(unit[1], unit[2], out)
-        else:
-            entries = self._reader.poll(self.batch)
-            moved = len(entries)
-            for line_idx, rec in entries:
-                self.process(line_idx, rec, out)
+        with self._sp_poll as poll:
+            if self.ingest_batches and hasattr(self._reader,
+                                               "poll_batches"):
+                # Columnar zero-decode path: whole RecordBatch frames go
+                # to process_batch; stray decoded records (a migrated
+                # JSONL history) take the per-record path.
+                for unit in self._reader.poll_batches(self.batch):
+                    if unit[0] == "batch":
+                        moved += unit[2].n
+                        self.process_batch(unit[1], unit[2], out)
+                    else:
+                        moved += 1
+                        self.process(unit[1], unit[2], out)
+            else:
+                entries = self._reader.poll(self.batch)
+                moved = len(entries)
+                for line_idx, rec in entries:
+                    self.process(line_idx, rec, out)
+            poll.n = moved
         next_off = self._reader.next_line
         if not moved:
             if next_off != self.offset:
@@ -682,9 +709,9 @@ class _Role:
                 print(f"FENCED {self.name} {self.owner}: {exc}", flush=True)
                 raise SystemExit(EXIT_FENCED)
             self.heartbeat()
-            self._idle_wait(idle_sleep)
             return 0
-        self.flush_batch(out)
+        with self._sp_flush:
+            self.flush_batch(out)
         try:
             if self.out_topic is not None:
                 # Append THEN checkpoint; the recovery scan makes the
@@ -692,7 +719,8 @@ class _Role:
                 # checkpoint cadence. Durable = retried under the
                 # storage-fault budget (degraded, not dead, through a
                 # transient ENOSPC).
-                self._ckpt_pending_bytes += self._append_outputs(out)
+                with self._sp_append:
+                    self._ckpt_pending_bytes += self._append_outputs(out)
             self.offset = next_off
             self._ckpt_dirty = True
             self.maybe_checkpoint()
@@ -701,7 +729,6 @@ class _Role:
             self.heartbeat(force=True)  # export the rejection before dying
             print(f"FENCED {self.name} {self.owner}: {exc}", flush=True)
             raise SystemExit(EXIT_FENCED)
-        self._m_pump.observe(moved)
         self._m_records.inc(moved)
         self.heartbeat()
         return moved

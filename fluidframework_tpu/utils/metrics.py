@@ -23,36 +23,52 @@ Design constraints (the observability contract of ISSUE 3):
   across processes; supervised children snapshot their registry into
   their heartbeat file and the supervisor folds the snapshots with
   `MetricsRegistry.merge` (counters/histograms add, gauges last-write).
+
+`span` times one layer boundary three ways at once: a
+`jax.profiler.TraceAnnotation` (so a profiler trace names the host
+work between device events), the `span_ms{span=...}` histogram family,
+and a bounded process-wide `SpanLog` of (name, start, end, parent id,
+span id, records) tuples.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from bisect import bisect_left
+from collections import deque
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 __all__ = [
     "DEFAULT_LATENCY_BUCKETS_MS",
+    "SPAN_BUCKETS_MS",
     "Counter",
     "FlightRecorder",
     "Gauge",
     "Histogram",
     "MetricsRegistry",
     "NullRegistry",
+    "Span",
+    "SpanLog",
     "dump_snapshot_line",
     "format_report",
     "get_flight_recorder",
     "get_registry",
+    "get_span_log",
     "histogram_quantile",
     "histogram_stats",
     "merge_snapshots",
+    "named_program",
     "set_enabled",
     "set_flight_recorder",
     "set_registry",
+    "set_span_log",
     "slo_summary",
+    "span",
 ]
 
 # Fixed latency buckets (ms): sub-millisecond ticks through 10s tails.
@@ -696,3 +712,166 @@ def set_flight_recorder(recorder: FlightRecorder) -> FlightRecorder:
     old = _default_recorder
     _default_recorder = recorder
     return old
+
+
+# ---------------------------------------------------------------------------
+# spans: the program's own layer timings
+# ---------------------------------------------------------------------------
+
+# Span durations run from tens of microseconds (an emit, a heartbeat)
+# to seconds (a checkpoint): finer first buckets than the op latencies.
+SPAN_BUCKETS_MS = (0.05, 0.1, 0.25) + DEFAULT_LATENCY_BUCKETS_MS
+
+
+class SpanLog:
+    """Bounded ring of finished spans, oldest first, each a tuple
+    ``(name, t0, t1, parent_id, span_id, n)`` on the `time.perf_counter`
+    clock (`n`: records the span handled, or None). A full ring drops
+    its oldest span per append and counts it: `overwritten`, and
+    `overwritten_until`, the latest end among dropped spans, so a
+    reader can tell whether its interval lost any (`lost_since`)."""
+
+    def __init__(self, capacity: int = 16384):
+        self.capacity = int(capacity)
+        self._ring: deque = deque(maxlen=self.capacity)
+        self.overwritten = 0
+        self.overwritten_until = float("-inf")
+        self._lock = threading.Lock()
+
+    def append(self, rec: tuple) -> None:
+        with self._lock:
+            if len(self._ring) == self.capacity:
+                self.overwritten += 1
+                end = self._ring[0][2]
+                if end > self.overwritten_until:
+                    self.overwritten_until = end
+            self._ring.append(rec)
+
+    def lost_since(self, t: float) -> bool:
+        """Whether a span that ended at or after `t` was overwritten."""
+        return self.overwritten_until >= t
+
+    def snapshot(self) -> List[tuple]:
+        with self._lock:
+            return list(self._ring)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._ring.clear()
+            self.overwritten = 0
+            self.overwritten_until = float("-inf")
+
+
+_span_log = SpanLog()
+_span_ids = itertools.count(1)
+_span_tls = threading.local()
+_trace_annotation: Any = None
+
+
+def get_span_log() -> SpanLog:
+    """The process's span ring (fed by every `span`)."""
+    return _span_log
+
+
+def set_span_log(log: SpanLog) -> SpanLog:
+    """Swap the span ring; returns the previous one (test isolation,
+    like `set_flight_recorder`)."""
+    global _span_log
+    old = _span_log
+    _span_log = log
+    return old
+
+
+def _annotation_cls():
+    """`jax.profiler.TraceAnnotation` once the process has imported
+    JAX, else None: tracing never imports JAX into a process that does
+    not use it (the CPU roles of a farm)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        _trace_annotation = getattr(prof, "TraceAnnotation", None)
+    return _trace_annotation
+
+
+class _Frame:
+    """One open span. The caller may set `n` (records handled)."""
+
+    __slots__ = ("span", "t0", "sid", "parent", "ann", "n")
+
+
+class _NullFrame:
+    __slots__ = ()
+
+    n = None
+
+    def __setattr__(self, name, value) -> None:
+        pass
+
+
+_NULL_FRAME = _NullFrame()
+
+
+class Span:
+    """A reusable span of one name: build it once (its histogram binds
+    to the registry current at construction, as every instrument
+    does), then ``with sp as f: ...; f.n = records``. Parent links come
+    from a per-thread stack of open spans, so nesting `with` blocks is
+    all a caller does. Off (a no-op) while `set_enabled(False)`."""
+
+    __slots__ = ("name", "_hist")
+
+    def __init__(self, name: str, labels: Dict[str, Any]):
+        self.name = name
+        self._hist = get_registry().histogram(
+            "span_ms", buckets=SPAN_BUCKETS_MS, span=name, **labels
+        )
+
+    def __enter__(self):
+        if not _enabled:
+            return _NULL_FRAME
+        stack = getattr(_span_tls, "stack", None)
+        if stack is None:
+            stack = _span_tls.stack = []
+        f = _Frame()
+        f.span = self
+        f.sid = next(_span_ids)
+        f.parent = stack[-1].sid if stack else None
+        f.n = None
+        cls = _trace_annotation or _annotation_cls()
+        f.ann = cls(self.name) if cls is not None else None
+        if f.ann is not None:
+            f.ann.__enter__()
+        stack.append(f)
+        f.t0 = time.perf_counter()
+        return f
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        stack = getattr(_span_tls, "stack", None)
+        if not stack or stack[-1].span is not self:
+            return  # entered while disabled
+        f = stack.pop()
+        if f.ann is not None:
+            f.ann.__exit__(None, None, None)
+        if _enabled:
+            self._hist.observe((t1 - f.t0) * 1000.0)
+            _span_log.append((self.name, f.t0, t1, f.parent, f.sid, f.n))
+
+
+def span(name: str, **labels) -> Span:
+    """A `Span` called `name` (a dotted layer name, ``<role>.<phase>``)
+    whose durations land in ``span_ms{span=name, **labels}``. Build it
+    once, at the caller's construction, and enter it per call."""
+    return Span(name, labels)
+
+
+def named_program(name: str) -> Callable:
+    """Decorator naming the function that `jax.jit` wraps next: the XLA
+    module (and the profiler's program events) become ``jit_<name>``
+    whatever the Python function is called, so a trace reader that
+    finds a program by name survives a refactor."""
+    def rename(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+
+    return rename

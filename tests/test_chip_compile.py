@@ -98,6 +98,7 @@ def _is_kernel(compiled) -> bool:
 
 def test_overlay_apply_chunk_compiles_for_v5e(one_chip):
     from fluidframework_tpu.ops.overlay_pallas import (
+        OVERLAY_KERNEL,
         make_overlay_table,
         overlay_apply_chunk,
     )
@@ -107,6 +108,8 @@ def test_overlay_apply_chunk_compiles_for_v5e(one_chip):
     ops = _specs(_op_batch(CHUNK), one_chip)
     compiled = overlay_apply_chunk.lower(table, ops).compile()
     assert _is_kernel(compiled)
+    # The kernel's instruction carries its pinned name (trace readers).
+    assert f"%{OVERLAY_KERNEL}." in compiled.as_text()
 
 
 def test_sequence_batch_compiles_for_v5e(one_chip):
