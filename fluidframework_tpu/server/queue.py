@@ -33,7 +33,7 @@ import time
 from typing import Any, Callable, Dict, Iterator, List, Optional, \
     Protocol, Tuple
 
-from ..utils.metrics import span
+from ..utils.metrics import get_registry, span
 
 
 class FencedError(RuntimeError):
@@ -913,6 +913,35 @@ class LeaseManager:
                 "expires": float(cur.get("expires", 0))}
 
 
+# The envelope's head as `FencedCheckpointStore.save` writes it
+# (`json.dumps` of {"fence", "owner", "state"}, in that order): the
+# fence and owner lie within the first few bytes of the file.
+_HEAD_BYTES = 4096
+_HEAD_FENCE = '{"fence": '
+_HEAD_OWNER = ', "owner": '
+_HEAD_STATE = ', "state": '
+_HEAD_DECODER = json.JSONDecoder()
+
+
+def _envelope_head(text: str) -> Optional[Tuple[int, Optional[str]]]:
+    """`(fence, owner)` from an envelope's head, or None unless `text`
+    opens with exactly ``{"fence": <int>, "owner": <string or null>,
+    "state": `` — the form `save` writes. Each value must be followed
+    by the next literal, so a head cut short never parses."""
+    if not text.startswith(_HEAD_FENCE):
+        return None
+    try:
+        fence, i = _HEAD_DECODER.raw_decode(text, len(_HEAD_FENCE))
+        if type(fence) is not int or not text.startswith(_HEAD_OWNER, i):
+            return None
+        owner, i = _HEAD_DECODER.raw_decode(text, i + len(_HEAD_OWNER))
+    except ValueError:
+        return None
+    if owner is not None and type(owner) is not str:
+        return None
+    return (fence, owner) if text.startswith(_HEAD_STATE, i) else None
+
+
 class FencedCheckpointStore:
     """Durable lambda checkpoints whose writes REJECT deposed owners.
 
@@ -924,25 +953,56 @@ class FencedCheckpointStore:
     owner than the one that first bound it — gets `FencedError`, so a
     deposed lease holder can never roll a successor's checkpoint back
     (the exactly-once recovery contract of ISSUE round 1).
+
+    The gate reads only the stored envelope's head (`_HEAD_BYTES`),
+    where `save` puts the fence and owner ahead of the state; an
+    envelope of any other form (key order, an owner longer than the
+    head, an empty or unreadable file) falls back to a full `load`,
+    counted by `checkpoint_fence_full_loads_total{role}`. A valid head
+    decides the check even if the body is damaged: that can only be
+    stricter than loading it, since a successor always binds a higher
+    fence.
     """
 
     def __init__(self, directory: str):
         self.dir = directory
         os.makedirs(directory, exist_ok=True)
-        self._phase_spans: Dict[str, tuple] = {}
+        self._instruments: Dict[str, tuple] = {}
 
     def _path(self, key: str) -> str:
         return os.path.join(self.dir, f"{key}.ckpt.json")
 
-    def _spans(self, key: str) -> tuple:
-        """The save's phase spans (fence, encode, write) for `key`,
-        built on its first save."""
-        sp = self._phase_spans.get(key)
-        if sp is None:
-            sp = self._phase_spans[key] = tuple(
+    def _instruments_of(self, key: str) -> tuple:
+        """The save's phase spans (fence, encode, write) and its
+        full-load fallback counter for `key`, built on its first
+        save."""
+        inst = self._instruments.get(key)
+        if inst is None:
+            inst = self._instruments[key] = tuple(
                 span(f"checkpoint.{phase}", role=key)
-                for phase in ("fence", "encode", "write"))
-        return sp
+                for phase in ("fence", "encode", "write")) + (
+                get_registry().counter(
+                    "checkpoint_fence_full_loads_total", role=key),)
+        return inst
+
+    def _bound_fence(self, key: str,
+                     full_loads) -> Optional[Tuple[int, Optional[str]]]:
+        """The stored envelope's `(fence, owner)`, or None where there
+        is no readable envelope (no check). Reads the head; an envelope
+        of another form is loaded whole and counted in `full_loads`."""
+        try:
+            with open(self._path(key)) as f:
+                head = _envelope_head(f.read(_HEAD_BYTES))
+        except FileNotFoundError:
+            return None
+        except (OSError, ValueError):
+            head = None
+        if head is not None:
+            return head
+        full_loads.inc()
+        cur = self.load(key)
+        return None if cur is None else (int(cur.get("fence", 0)),
+                                         cur.get("owner"))
 
     def load(self, key: str) -> Optional[dict]:
         """The checkpoint envelope ({"fence", "owner", "state"}) or
@@ -961,10 +1021,12 @@ class FencedCheckpointStore:
         (the checkpoint-bytes metric's source)."""
         import fcntl
 
-        sp_fence, sp_encode, sp_write = self._spans(key)
+        sp_fence, sp_encode, sp_write, full_loads = self._instruments_of(key)
         lock_path = self._path(key) + ".lock"
         with open(lock_path, "a+") as lk, contextlib.ExitStack() as gate:
-            # The fence phase: lock wait, previous envelope, fence check.
+            # The fence phase: lock wait, the stored envelope's head
+            # (its full load only where the head is of another form,
+            # and freeing it), fence check.
             gate.enter_context(sp_fence)
             if lock_timeout_s is None:
                 fcntl.flock(lk.fileno(), fcntl.LOCK_EX)
@@ -984,13 +1046,10 @@ class FencedCheckpointStore:
                             )
                         time.sleep(0.005)
             try:
-                cur = self.load(key)
-                if cur is not None:
-                    _check_fence(
-                        int(cur.get("fence", 0)), cur.get("owner"),
-                        fence, owner, f"checkpoint {key!r}",
-                    )
-                del cur  # freeing the previous envelope is fence work too
+                bound = self._bound_fence(key, full_loads)
+                if bound is not None:
+                    _check_fence(*bound, fence, owner,
+                                 f"checkpoint {key!r}")
                 gate.close()
                 tmp = self._path(key) + f".tmp.{os.getpid()}"
                 with sp_encode:
