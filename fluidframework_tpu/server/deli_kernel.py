@@ -28,7 +28,10 @@ Division of labor (the correctness spine):
 
 Doc slots grow by doubling and evict for free: parking a document just
 frees its slot (the mirror is authoritative for parked docs); touching
-it again scatters the state row back in before the next kernel call.
+it again reloads the state row (`SEQ_POOL_LOAD_PROGRAM`) before the
+next kernel call. A `KernelDeliRole` bounds residency by what it
+observes (`KernelDeliRole.residency_budget`), and its pool shrinks to
+that budget once it holds twice as many rows.
 
 Two frontends wrap the shared `PackedDeliCore`:
 
@@ -51,9 +54,12 @@ the cost.
 
 from __future__ import annotations
 
+import functools
 import time
+from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from ..ops import sequencer_kernel as _sk
@@ -71,7 +77,7 @@ from ..protocol.messages import (
     SequencedMessage,
     trace_submit_ts,
 )
-from ..utils.metrics import get_registry, span
+from ..utils.metrics import get_registry, named_program, span
 from .log import LogConsumer, MessageLog
 from .sequencer import (
     NACK_FUTURE_REFSEQ,
@@ -87,10 +93,43 @@ __all__ = [
     "KernelDeliLambda",
     "KernelDeliRole",
     "PackedDeliCore",
+    "RESIDENT_FLOOR",
+    "RESIDENT_HORIZON_S",
+    "SEQ_POOL_LOAD_PROGRAM",
     "SeqPool",
 ]
 
 SYSTEM_CLIENT = -1  # mirrors lambdas.SYSTEM_CLIENT (import would cycle)
+
+# The residency rule of every `KernelDeliRole`: the pool holds at most
+# the power of two covering twice the documents its pumps touched over
+# the last RESIDENT_HORIZON_S seconds, and never fewer than
+# RESIDENT_FLOOR (a partition of up to that many documents keeps them
+# all resident, so the rule parks nothing there).
+RESIDENT_HORIZON_S = 1.0
+RESIDENT_FLOOR = 16384
+
+# The XLA module name of the pool's row reload (``jit_seq_pool_load``):
+# profiler readers find its device time by this name.
+SEQ_POOL_LOAD_PROGRAM = "seq_pool_load"
+LOAD_MIN_ROWS = 8  # the smallest reload bucket
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+@named_program(SEQ_POOL_LOAD_PROGRAM)
+def _load_rows(state, idx, seq, min_seq, connected, ref_seq, client_seq):
+    """Write whole state rows: row ``idx[i]`` takes row i of every
+    field. Padding rows carry an index past the last row and are
+    dropped."""
+    def put(a, v):
+        return a.at[idx].set(v, mode="drop")
+
+    return _sk.SequencerState(
+        seq=put(state.seq, seq), min_seq=put(state.min_seq, min_seq),
+        connected=put(state.connected, connected),
+        ref_seq=put(state.ref_seq, ref_seq),
+        client_seq=put(state.client_seq, client_seq),
+    )
 
 
 def _pow2(n: int, lo: int = 8) -> int:
@@ -163,11 +202,19 @@ class SeqPool:
     verdicts, authoritative for CHECKPOINTS and for parked (evicted)
     documents. Slots are recycled: parking costs nothing (the row is
     overwritten on the next load), touching a parked doc queues a row
-    scatter that flushes in one batched write before the next run.
+    reload that flushes in one batched write before the next run.
+
+    Eviction victims come off two queues of the resident documents that
+    the current pump has not touched, each oldest-touched first:
+    MSN-cold documents (MSN caught up with the head) before lagging
+    ones. A document's MSN and head change only in a pump that touches
+    it, so `begin` files the previous pump's documents once, and a pick
+    is O(1).
     """
 
     def __init__(self, n_docs: int = 8, n_clients: int = 8,
-                 max_resident: Optional[int] = None, mesh=None):
+                 max_resident: Optional[int] = None, mesh=None,
+                 labels: Optional[Dict[str, str]] = None):
         """`mesh` (a 1-D `jax.sharding.Mesh` over a ``docs`` axis, see
         `parallel.mesh.make_docs_mesh`/`shared_docs_mesh`) shards the
         `[D, C]` pool across its devices: `n_docs` is kept a multiple
@@ -177,7 +224,8 @@ class SeqPool:
         gather once per chunk. The host mirror, slot allocation,
         grow/evict/park, and checkpoint format are IDENTICAL to the
         single-device pool — sharding only changes where slot rows
-        live."""
+        live. `labels` label the pool's counters (a role's
+        ``{role: name}``)."""
         self.mesh = mesh
         self._n_shards = int(mesh.size) if mesh is not None else 1
         self.n_docs = _mul_of(max(1, n_docs), self._n_shards)
@@ -198,30 +246,56 @@ class SeqPool:
         self.docs: Dict[str, dict] = {}
         self.slot_owner: Dict[int, str] = {}
         self.free: List[int] = list(range(self.n_docs - 1, -1, -1))
+        # Queued row reloads, (slot, mirror entry); an entry whose doc
+        # no longer owns the slot (parked since) is dropped at flush.
         self._loads: List[Tuple[int, dict]] = []
+        # Logical slots whose row has held a document: a new document
+        # on a never-used row needs no reload (the row is still zero).
+        self._used = np.zeros(self.n_docs, bool)
         self._need_clients = self.n_clients
         self._clock = 0
-        self._active: set = set()
+        # Documents touched this pump, in touch order, and how many of
+        # them were MSN-cold when touched; the victim queues (doc ->
+        # None, oldest touch first) hold every other resident doc.
+        self._active: Dict[str, None] = {}
+        self._active_cold = 0
+        self._cold: "OrderedDict[str, None]" = OrderedDict()
+        self._warm: "OrderedDict[str, None]" = OrderedDict()
         # Pool instrumentation (per-event counters here; occupancy
         # gauges are refreshed once per kernel pump by the core).
         m = get_registry()
-        self._m_grows = m.counter("deli_pool_grows_total")
-        self._m_evicts = m.counter("deli_pool_evictions_total")
+        labels = labels or {}
+        self._m_grows = m.counter("deli_pool_grows_total", **labels)
+        self._m_shrinks = m.counter("deli_pool_shrinks_total", **labels)
+        self._m_evicts = m.counter("deli_pool_evictions_total", **labels)
+        self._m_loads = m.counter("deli_pool_loads_total", **labels)
         # ROADMAP (e)/(c) observability: which policy picked each
         # eviction victim, how cold the resident set looked at decision
         # time, and how many client columns compaction reclaimed.
         self._m_evict_policy = {
-            p: m.counter("deli_pool_evictions_by_policy_total", policy=p)
+            p: m.counter("deli_pool_evictions_by_policy_total", policy=p,
+                         **labels)
             for p in ("msn_cold", "lru")
         }
-        self._m_cold = m.gauge("deli_pool_cold_resident_docs")
-        self._m_reclaims = m.counter("deli_pool_col_reclaims_total")
-        self._m_compactions = m.counter("deli_pool_compactions_total")
+        self._m_cold = m.gauge("deli_pool_cold_resident_docs", **labels)
+        self._m_reclaims = m.counter("deli_pool_col_reclaims_total",
+                                     **labels)
+        self._m_compactions = m.counter("deli_pool_compactions_total",
+                                        **labels)
 
     # ------------------------------------------------------------ slots
 
     def begin(self) -> None:
+        """Start a pump: file the previous pump's resident documents at
+        the young end of their victim queue (their touches are the
+        latest), cold or lagging by the MSN their verdicts left."""
+        docs, cold, warm = self.docs, self._cold, self._warm
+        for doc_id in self._active:
+            h = docs.get(doc_id)
+            if h is not None and h["slot"] is not None:
+                (cold if h["min_seq"] >= h["seq"] else warm)[doc_id] = None
         self._active.clear()
+        self._active_cold = 0
 
     def touch(self, doc_id: str) -> dict:
         """Resident host-mirror entry for `doc_id` (its `"slot"` is the
@@ -231,7 +305,8 @@ class SeqPool:
         huge — gets the oracle's unknown-client verdict without
         aliasing a real client's state)."""
         h = self.docs.get(doc_id)
-        if h is None:
+        new = h is None
+        if new:
             h = {"slot": None, "seq": 0, "min_seq": 0, "clients": {},
                  "cmap": {}, "t": 0}
             self.docs[doc_id] = h
@@ -246,10 +321,21 @@ class SeqPool:
             slot = self._alloc()
             h["slot"] = slot
             self.slot_owner[slot] = doc_id
-            self._loads.append((slot, h))
+            if not new or self._used[slot]:
+                self._loads.append((slot, h))
+            self._used[slot] = True
+        elif doc_id in self._cold:  # active: no longer a victim
+            del self._cold[doc_id]
+        else:
+            self._warm.pop(doc_id, None)
         self._clock += 1
         h["t"] = self._clock
-        self._active.add(doc_id)
+        active = self._active
+        if doc_id in active:
+            del active[doc_id]  # touched again: to the young end
+        elif h["min_seq"] >= h["seq"]:
+            self._active_cold += 1
+        active[doc_id] = None
         return h
 
     def col_of_join(self, h: dict, cid) -> int:
@@ -261,53 +347,46 @@ class SeqPool:
             col = cmap[cid] = len(cmap) + 1  # col 0 is scratch
         return col
 
+    def _victim(self) -> Optional[Tuple[str, str]]:
+        """(doc, policy) of the next eviction: the least recently
+        touched MSN-cold document, else the least recently touched
+        one (ROADMAP (e): a doc whose MSN caught its head is quiescent
+        — every connected client acked everything, or none remain).
+        None when every resident doc is active."""
+        for queue, policy in ((self._cold, "msn_cold"),
+                              (self._warm, "lru")):
+            if queue:
+                return next(iter(queue)), policy
+        return None
+
     def _alloc(self) -> int:
         # Soft resident budget: once resident docs reach max_resident,
-        # every new residency first tries to park the coldest doc not
-        # touched this pump and reuse its slot — the cap holds except
-        # when a single pump's active set exceeds it (actives can't be
-        # parked; the pool then grows to cover the pump).
+        # every new residency first parks the victim and reuses its
+        # slot — the cap holds except when a single pump's active set
+        # exceeds it (actives can't be parked; the pool then grows to
+        # cover the pump).
         if (self.max_resident is not None
                 and len(self.slot_owner) >= self.max_resident):
-            # Victim pick is hot/cold by MSN progress (ROADMAP (e)):
-            # a doc whose MSN has caught its head seq is quiescent —
-            # every connected client acked everything (or none remain)
-            # — and is evicted ahead of any still-lagging doc; LRU by
-            # pump breaks ties and is the fallback when nothing is
-            # cold. The mirror already tracks both numbers, so the
-            # scan costs nothing extra.
-            victim = None
-            victim_key = None
-            cold_resident = 0
-            for doc_id, h in self.docs.items():
-                if h["slot"] is None:
-                    continue
-                cold = h["min_seq"] >= h["seq"]
-                if cold:
-                    cold_resident += 1
-                if doc_id in self._active:
-                    continue
-                key = (not cold, h["t"])
-                if victim_key is None or key < victim_key:
-                    victim, victim_key = doc_id, key
-            self._m_cold.set(cold_resident)
+            self._m_cold.set(len(self._cold) + self._active_cold)
+            victim = self._victim()
             if victim is not None:
-                self.park(
-                    victim,
-                    policy="lru" if victim_key[0] else "msn_cold",
-                )
+                self.park(*victim)
         if not self.free:
             old = self.n_docs
             self.n_docs = _mul_of(max(8, old * 2), self._n_shards)
             self.free.extend(range(self.n_docs - 1, old - 1, -1))
+            self._used = np.concatenate(
+                [self._used, np.zeros(self.n_docs - old, bool)])
             self._m_grows.inc()
         return self.free.pop()
 
     def park(self, doc_id: str, policy: Optional[str] = None) -> None:
         """Evict a document's slot. Free: the host mirror is already
         complete, so the stale device row is simply abandoned until the
-        slot's next occupant scatters over it. `policy` records which
-        rule picked the victim (msn_cold / lru) for the pool gauges."""
+        slot's next occupant reloads over it (a reload still queued for
+        the doc is dropped at flush, since the doc no longer owns the
+        slot). `policy` records which rule picked the victim (msn_cold
+        / lru) for the pool gauges."""
         h = self.docs[doc_id]
         slot = h["slot"]
         if slot is None:
@@ -315,16 +394,44 @@ class SeqPool:
         h["slot"] = None
         self.slot_owner.pop(slot, None)
         self.free.append(slot)
-        if self._loads:
-            # Drop any queued reload for the freed slot: the slot's
-            # NEXT occupant queues its own load, and a stale one would
-            # race it in the batched scatter (duplicate indices with
-            # unspecified update order — the evicted doc's state could
-            # overwrite the new occupant's row).
-            self._loads = [(s, hh) for s, hh in self._loads if s != slot]
+        if doc_id in self._cold:
+            del self._cold[doc_id]
+        else:
+            self._warm.pop(doc_id, None)
         self._m_evicts.inc()
         if policy is not None:
             self._m_evict_policy[policy].inc()
+
+    def fit(self, budget: int) -> None:
+        """Hold at most `budget` documents resident from the next
+        residency on; a pool with at least twice `budget` rows parks
+        victims down to the budget and shrinks to `budget` rows, the
+        documents it keeps reloaded into the first rows. Call between
+        pumps (`begin` first): a pump's active documents never park,
+        and a pool still holding more than `budget` of them keeps its
+        rows."""
+        self.max_resident = budget
+        rows = _mul_of(max(LOAD_MIN_ROWS, budget), self._n_shards)
+        if self.n_docs < 2 * rows:
+            return
+        while len(self.slot_owner) > rows:
+            victim = self._victim()
+            if victim is None:
+                return
+            self.park(*victim)
+        keep = list(self.slot_owner.values())
+        for slot, doc_id in enumerate(keep):
+            self.docs[doc_id]["slot"] = slot
+        self.slot_owner = dict(enumerate(keep))
+        self.n_docs = rows
+        self.free = list(range(rows - 1, len(keep) - 1, -1))
+        self._used = np.zeros(rows, bool)
+        self._used[:len(keep)] = True
+        self._phys = np.arange(rows, dtype=np.int64)
+        self.state = _sk.make_state(rows, self.n_clients)
+        self._placed = False
+        self._loads = [(slot, self.docs[d]) for slot, d in enumerate(keep)]
+        self._m_shrinks.inc()
 
     # ------------------------------------------------- column compaction
 
@@ -481,17 +588,17 @@ class SeqPool:
         self.state = self.state._replace(**new_fields)
         return True
 
-    def prepare(self) -> None:
+    def prepare(self) -> int:
         """Grow the packed state to the logical (D, C), flush queued
-        doc-row loads in one batched scatter, and (sharded pools)
-        place the result across the mesh — the kernel's in/out specs
-        then keep it sharded between pumps for free. An already-placed
-        pool takes the SCOPED scatter path: only the shards owning a
-        grown/restored row are rebuilt, the rest keep their buffers
-        (growth still re-places everything — a new shape means new
-        buffers no matter what)."""
-        import jax.numpy as jnp
-
+        doc-row loads in one batched write, and (sharded pools) place
+        the result across the mesh — the kernel's in/out specs then
+        keep it sharded between pumps for free. Returns the rows
+        loaded. One device, the write is `SEQ_POOL_LOAD_PROGRAM` at a
+        power-of-two row bucket (`warm_loads` compiles them all). An
+        already-placed sharded pool takes the SCOPED scatter path
+        instead: only the shards owning a reloaded row are rebuilt,
+        the rest keep their buffers (growth still re-places everything
+        — a new shape means new buffers no matter what)."""
         need_c = _pow2(self._need_clients, self.n_clients)
         d, c = self.state.connected.shape
         if self.n_docs != d or need_c != c:
@@ -511,48 +618,92 @@ class SeqPool:
                                   dtype=np.int64),
                     ])
             self.n_clients = need_c
-        if not self._loads:
+        # One load per slot still owned by its doc (the latest queued).
+        loads = {slot: h for slot, h in self._loads if h["slot"] == slot}
+        self._loads = []
+        if not loads:
             if self.mesh is not None and not self._placed:
                 self.state = self._place(self.state)
                 self._placed = True
-            return
-        n, C = len(self._loads), self.n_clients
-        idx = np.empty(n, np.int64)
-        seqv = np.empty(n, np.int32)
-        minv = np.empty(n, np.int32)
-        conn = np.zeros((n, C), bool)
-        ref = np.zeros((n, C), np.int32)
-        cseq = np.zeros((n, C), np.int32)
-        for i, (slot, h) in enumerate(self._loads):
-            idx[i] = slot
-            seqv[i] = h["seq"]
-            minv[i] = h["min_seq"]
-            cmap = h["cmap"]
-            for cid, (r, cs) in h["clients"].items():
-                col = cmap[cid]
-                conn[i, col] = True
-                ref[i, col] = r
-                cseq[i, col] = cs
-        self._loads = []
-        idx = self._phys[idx]  # logical slots -> physical state rows
-        updates = {"seq": seqv, "min_seq": minv, "connected": conn,
-                   "ref_seq": ref, "client_seq": cseq}
+            return 0
+        idx, updates = self._rows(loads)
+        self._m_loads.inc(len(idx))
         if (self.mesh is not None and self._placed
                 and self._scatter_rows_placed(idx, updates)):
-            return
-        jidx = jnp.asarray(idx)
-        self.state = self.state._replace(
-            seq=self.state.seq.at[jidx].set(jnp.asarray(seqv)),
-            min_seq=self.state.min_seq.at[jidx].set(jnp.asarray(minv)),
-            connected=self.state.connected.at[jidx].set(jnp.asarray(conn)),
-            ref_seq=self.state.ref_seq.at[jidx].set(jnp.asarray(ref)),
-            client_seq=self.state.client_seq.at[jidx].set(jnp.asarray(cseq)),
-        )
+            return len(idx)
+        self.state = self._load(idx, updates)
         if self.mesh is not None:
             # The host-side scatter loses the docs layout; re-place
             # before the next kernel call (one batched transfer).
             self.state = self._place(self.state)
             self._placed = True
+        return len(idx)
+
+    def _rows(self, loads: Dict[int, dict]):
+        """(physical rows, field -> row values) of the state rows the
+        mirror entries in `loads` (slot -> entry) hold, built as
+        arrays."""
+        n, C = len(loads), self.n_clients
+        hs = list(loads.values())
+        idx = self._phys[np.fromiter(loads, np.int64, n)]
+        seqv = np.fromiter((h["seq"] for h in hs), np.int32, n)
+        minv = np.fromiter((h["min_seq"] for h in hs), np.int32, n)
+        counts = np.fromiter((len(h["clients"]) for h in hs), np.int64, n)
+        flat = np.array([(cmap[cid], r, cs) for h in hs
+                         for cmap in (h["cmap"],)
+                         for cid, (r, cs) in h["clients"].items()],
+                        np.int64).reshape(-1, 3)
+        row = np.repeat(np.arange(n), counts)
+        col = flat[:, 0]
+        conn = np.zeros((n, C), bool)
+        ref = np.zeros((n, C), np.int32)
+        cseq = np.zeros((n, C), np.int32)
+        conn[row, col] = True
+        ref[row, col] = flat[:, 1]
+        cseq[row, col] = flat[:, 2]
+        return idx, {"seq": seqv, "min_seq": minv, "connected": conn,
+                     "ref_seq": ref, "client_seq": cseq}
+
+    def _load(self, idx, updates):
+        """The state with `updates` written at rows `idx`: one
+        `SEQ_POOL_LOAD_PROGRAM` call, padded to a power-of-two bucket
+        whose padding rows index past the last row (dropped)."""
+        import jax.numpy as jnp
+
+        n = len(idx)
+        b = _pow2(n, LOAD_MIN_ROWS)
+        d = self.state.seq.shape[0]
+        pidx = np.full(b, d, np.int32)
+        pidx[:n] = idx
+        cols = []
+        for name in _sk.SequencerState._fields:
+            v = updates[name]
+            pad = np.zeros((b,) + v.shape[1:], v.dtype)
+            pad[:n] = v
+            cols.append(jnp.asarray(pad))
+        return _load_rows(self.state, jnp.asarray(pidx), *cols)
+
+    def warm_loads(self) -> None:
+        """Compile the row reload at every bucket a flush can take at
+        the pool's current shape (one device; a placed sharded pool
+        reloads on the host and compiles nothing)."""
+        if self.mesh is not None:
+            return
+        saved = self.state
+        d = saved.seq.shape[0]
+        b = LOAD_MIN_ROWS
+        while True:
+            self.state = _sk.make_state(d, self.n_clients)
+            idx = np.full(min(b, d), d, np.int64)
+            rows = {name: np.zeros((len(idx),) + getattr(
+                        self.state, name).shape[1:],
+                        getattr(self.state, name).dtype)
+                    for name in _sk.SequencerState._fields}
+            jax.block_until_ready(self._load(idx, rows))
+            if b >= d:
+                break
+            b <<= 1
+        self.state = saved
 
     def run_chunk(self, kind, client, cseq, ref, groups, dedup: bool,
                   aborted=None):
@@ -561,25 +712,43 @@ class SeqPool:
         Sharded pools run the shard_map'd kernel — same abort/dedup
         semantics, doc rows resident on their owning device — and the
         verdict gather is the single device_get below."""
-        import jax
-        import jax.numpy as jnp
-
         if aborted is None:
             aborted = _sk.no_aborts(self.n_docs)
+        self.state, aborted, res = self._sequence(
+            self.state, kind, client, cseq, ref, groups, dedup, aborted)
+        return jax.device_get(res), aborted
+
+    def _sequence(self, state, kind, client, cseq, ref, groups,
+                  dedup: bool, aborted):
+        """One sequencer call on `state` (donated): the shard_map'd
+        program over the mesh, else the one-device program."""
+        import jax.numpy as jnp
+
         batch = _sk.SeqBatch(
             kind=jnp.asarray(kind), client=jnp.asarray(client),
             client_seq=jnp.asarray(cseq), ref_seq=jnp.asarray(ref),
         )
         if self.mesh is not None:
             fn = _sk.sharded_sequence_fn(self.mesh, dedup=bool(dedup))
-            self.state, aborted, res = fn(
-                self.state, aborted, batch, jnp.asarray(groups)
-            )
-        else:
-            self.state, aborted, res = _sk.sequence_batch_grouped(
-                self.state, batch, jnp.asarray(groups), dedup, aborted
-            )
-        return jax.device_get(res), aborted
+            return fn(state, aborted, batch, jnp.asarray(groups))
+        return _sk.sequence_batch_grouped(
+            state, batch, jnp.asarray(groups), dedup, aborted)
+
+    def warm_sequencer(self, max_cols: int, dedup: bool) -> None:
+        """Compile the sequencer at every batch width a pump can take
+        at the pool's current shape ([D, B], B a power of two from 8
+        to `max_cols`), on a throwaway state."""
+        d, c = self.state.connected.shape
+        b = 8
+        while b <= max_cols:
+            state = _sk.make_state(d, c)
+            if self.mesh is not None:
+                state = self._place(state)
+            z = np.zeros((d, b), np.int32)
+            jax.block_until_ready(self._sequence(
+                state, z + _sk.SUB_PAD, z, z, z, z + NO_GROUP, dedup,
+                _sk.no_aborts(d)))
+            b *= 2
 
     # ---------------------------------------------------- verdict mirror
 
@@ -681,12 +850,18 @@ class PackedDeliCore:
 
     def __init__(self, n_docs: int = 8, n_clients: int = 8,
                  max_resident: Optional[int] = None, max_cols: int = 256,
-                 dedup: bool = False, mesh=None, kernel_span=None):
+                 dedup: bool = False, mesh=None, kernel_span=None,
+                 prepare_span=None,
+                 labels: Optional[Dict[str, str]] = None):
         """`kernel_span` (a `utils.metrics.Span`) times each sequencer
-        call through its verdict fetch; the role passes its own
-        ``<role>.kernel``."""
-        self.pool = SeqPool(n_docs, n_clients, max_resident, mesh=mesh)
+        call through its verdict fetch, `prepare_span` the pool's row
+        reloads before it (n = rows loaded); the role passes its own
+        ``<role>.kernel`` and ``<role>.pool.prepare``, and its metric
+        `labels` for the pool's counters."""
+        self.pool = SeqPool(n_docs, n_clients, max_resident, mesh=mesh,
+                            labels=labels)
         self._sp_kernel = kernel_span or span("deli.kernel")
+        self._sp_prepare = prepare_span or span("deli.pool.prepare")
         self.max_cols = max(8, max_cols)
         self.dedup = dedup
         # Submissions accumulate as ORDERED segments: lists of
@@ -783,9 +958,19 @@ class PackedDeliCore:
         add = self.add
         return [add(slot, SUB_OP, col, cs, rf, g) for col, cs, rf in ops]
 
+    def warm_up(self) -> None:
+        """Flush queued reloads, then compile every program a pump can
+        run at the pool's current shape: the sequencer at each batch
+        width and the row reload at each bucket."""
+        with self._sp_prepare as sp:
+            sp.n = self.pool.prepare()
+        self.pool.warm_sequencer(self.max_cols, self.dedup)
+        self.pool.warm_loads()
+
     def run(self, as_arrays: bool = False) -> _FlatResults:
         pool = self.pool
-        pool.prepare()
+        with self._sp_prepare as sp:
+            sp.n = pool.prepare()
         n = self._n_subs
         if n == 0:
             if as_arrays:
@@ -1183,9 +1368,13 @@ class KernelDeliRole(_Role):
             )
         labels = self._metric_labels()
         self._sp_kernel = span(f"{self.span_prefix}.kernel", **labels)
+        self._sp_prepare = span(f"{self.span_prefix}.pool.prepare",
+                                **labels)
         self._sp_emit = span(f"{self.span_prefix}.emit", **labels)
-        self.core = PackedDeliCore(dedup=True, mesh=self.mesh,
-                                   kernel_span=self._sp_kernel)
+        self.core = self._new_core()
+        # Documents the pumps touched, oldest touch first, with the
+        # time of that touch (`residency_budget`'s working set).
+        self._recent: "OrderedDict[str, float]" = OrderedDict()
         self._pending: List[tuple] = []  # ("rec", off, dict) |
         #                                 ("cols", start_off, RecordBatch)
         # Blob pass-through is only legal when the output topic can
@@ -1206,10 +1395,40 @@ class KernelDeliRole(_Role):
     def restore_state(self, state: Any) -> None:
         from .supervisor import unwrap_ranged_state
 
-        core = PackedDeliCore(dedup=True, mesh=self.mesh,
-                              kernel_span=self._sp_kernel)
+        core = self._new_core()
         core.pool.restore_docs(unwrap_ranged_state(state))
         self.core = core
+
+    def _new_core(self) -> PackedDeliCore:
+        return PackedDeliCore(dedup=True, mesh=self.mesh,
+                              kernel_span=self._sp_kernel,
+                              prepare_span=self._sp_prepare,
+                              labels=self._metric_labels())
+
+    # -------------------------------------------------------- residency
+
+    def residency_budget(self, now: Optional[float] = None) -> int:
+        """The resident budget the role derives from what its pumps
+        touched: the power of two covering twice the documents touched
+        over the last `RESIDENT_HORIZON_S` seconds (`time.monotonic`),
+        at least `RESIDENT_FLOOR`. A pump's active set is never parked
+        (`SeqPool._alloc`), so one larger than the budget grows the
+        pool to cover it."""
+        now = time.monotonic() if now is None else now
+        recent = self._recent
+        cut = now - RESIDENT_HORIZON_S
+        while recent and next(iter(recent.values())) < cut:
+            recent.popitem(last=False)
+        return max(RESIDENT_FLOOR, _pow2(2 * len(recent)))
+
+    def warm_up(self) -> None:
+        """Fit the pool to the residency budget now, then compile every
+        program a pump can run at that size (`PackedDeliCore.warm_up`):
+        a pump after this compiles nothing until the pool's shape
+        changes."""
+        self.core.pool.begin()
+        self.core.pool.fit(self.residency_budget())
+        self.core.warm_up()
 
     # ------------------------------------------------------------- pump
 
@@ -1244,6 +1463,8 @@ class KernelDeliRole(_Role):
         core = self.core
         pool = core.pool
         core.begin()
+        now = time.monotonic()
+        pool.fit(self.residency_budget(now))
         touch, add, col_of_join = core.touch, core.add, pool.col_of_join
         docs_cache: Dict[str, tuple] = {}  # touch once per doc per pump
         plan: List[tuple] = []
@@ -1322,6 +1543,10 @@ class KernelDeliRole(_Role):
             self._plan_cols(plan, ent[2], ent[1], doc_entry,
                             plan_record, plan_boxcar, passthrough)
         self._pending = []
+        recent = self._recent
+        for doc in docs_cache:
+            recent.pop(doc, None)
+            recent[doc] = now
         res = core.run(as_arrays=emit_cols)
         with self._sp_emit:
             if emit_cols:

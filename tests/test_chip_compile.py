@@ -122,6 +122,26 @@ def test_sequence_batch_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+@pytest.mark.parametrize("rows", [8, 16384])
+def test_pool_load_compiles_for_v5e(one_chip, rows):
+    """The pool's row reload at its smallest and largest bucket over the
+    churning partition's 16,384-row, 16-column pool."""
+    from fluidframework_tpu.ops.sequencer_kernel import make_state
+    from fluidframework_tpu.server.deli_kernel import (
+        SEQ_POOL_LOAD_PROGRAM,
+        _load_rows,
+    )
+
+    state = _specs(lambda: make_state(16384, 16), one_chip)
+    idx = _specs(lambda: jnp.zeros(rows, jnp.int32), one_chip)
+    vals = [_specs(lambda a=a: jnp.zeros((rows,) + a.shape[1:], a.dtype),
+                   one_chip) for a in jax.eval_shape(
+                       lambda: make_state(16384, 16))]
+    compiled = _load_rows.lower(state, idx, *vals).compile()
+    assert SEQ_POOL_LOAD_PROGRAM in compiled.as_text()
+    assert compiled.memory_analysis() is not None
+
+
 def test_sharded_sequence_fn_compiles_for_v5e_2x2(topo):
     from fluidframework_tpu.ops.sequencer_kernel import (
         no_aborts,

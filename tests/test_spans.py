@@ -187,12 +187,15 @@ def test_kernel_deli_step_and_checkpoint_span_trees(tmp_path, fresh):
     assert len(roots) == 1 and roots[0][3] is None
     assert roots[0][5] == len(recs)
     assert _tree(spans, roots[0][4]) == [
-        (1, "deli.poll"), (1, "deli.flush"), (2, "deli.kernel"),
-        (2, "deli.emit"), (1, "deli.append"), (1, "deli.heartbeat"),
+        (1, "deli.poll"), (1, "deli.flush"), (2, "deli.pool.prepare"),
+        (2, "deli.kernel"), (2, "deli.emit"), (1, "deli.append"),
+        (1, "deli.heartbeat"),
     ]
     by_name = {r[0]: r for r in spans}
     assert by_name["deli.poll"][5] == len(recs)
     assert by_name["deli.kernel"][5] == len(recs)
+    # New documents on never-used rows: nothing to reload.
+    assert by_name["deli.pool.prepare"][5] == 0
 
     log.clear()
     role.checkpoint()
@@ -285,6 +288,19 @@ def test_sequencer_program_names_are_pinned():
     sharded = sk.sharded_sequence_fn(mesh, dedup=True).lower(
         state, aborted, batch, groups)
     assert sk.SEQUENCER_PROGRAM in _module_name(sharded)
+
+
+def test_pool_load_program_name_is_pinned():
+    import jax.numpy as jnp
+
+    from fluidframework_tpu.ops import sequencer_kernel as sk
+    from fluidframework_tpu.server import deli_kernel as dk
+
+    state = sk.make_state(8, 4)
+    idx = jnp.zeros(8, jnp.int32)
+    rows = [jnp.zeros((8,) + a.shape[1:], a.dtype) for a in state]
+    lowered = dk._load_rows.lower(state, idx, *rows)
+    assert dk.SEQ_POOL_LOAD_PROGRAM in _module_name(lowered)
 
 
 def test_overlay_program_names_are_pinned():
