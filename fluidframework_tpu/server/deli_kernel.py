@@ -55,6 +55,7 @@ the cost.
 from __future__ import annotations
 
 import functools
+import json
 import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Tuple
@@ -79,6 +80,7 @@ from ..protocol.messages import (
 )
 from ..utils.metrics import get_registry, named_program, span
 from .log import LogConsumer, MessageLog
+from .queue import EncodedJson
 from .sequencer import (
     NACK_FUTURE_REFSEQ,
     NACK_STALE_REFSEQ,
@@ -193,6 +195,45 @@ def _nack_reason(code: int, ref: int, msn: int, head: int, cseq: int,
     return f"clientSeq {cseq} out of order"
 
 
+def _doc_state(doc_id: str, h: dict) -> dict:
+    """One mirror entry in `DocumentSequencer.checkpoint()` format."""
+    return {
+        "doc_id": doc_id,
+        "seq": h["seq"],
+        "min_seq": h["min_seq"],
+        "clients": {
+            str(cid): {
+                "ref_seq": rc[0], "client_seq": rc[1], "last_update": 0.0,
+            }
+            for cid, rc in h["clients"].items()
+        },
+    }
+
+
+def _encode_doc(doc_id: str, h: dict) -> bytes:
+    """``, "<doc_id>": <state>``: the item `json.dumps` writes for this
+    document inside the checkpoint's per-doc map, after the separator
+    that precedes every item but the first. Integer fields format
+    directly; any other value (or key) takes `json.dumps` itself."""
+    seq, msn = h["seq"], h["min_seq"]
+    if type(doc_id) is str and type(seq) is int and type(msn) is int:
+        try:
+            clients = ", ".join([
+                f'"{cid}": {{"ref_seq": {r}, "client_seq": {c}, '
+                f'"last_update": 0.0}}'
+                if type(cid) is int and type(r) is int and type(c) is int
+                else None  # join raises: the json.dumps path below
+                for cid, (r, c) in h["clients"].items()])
+        except (TypeError, ValueError):
+            pass
+        else:
+            k = json.dumps(doc_id)
+            return (f', {k}: {{"doc_id": {k}, "seq": {seq}, "min_seq": '
+                    f'{msn}, "clients": {{{clients}}}}}').encode()
+    item = json.dumps({doc_id: _doc_state(doc_id, h)})
+    return (", " + item[1:-1]).encode()
+
+
 class SeqPool:
     """Dense [D, C] kernel-state pool with doc-slot grow/evict and
     scalar-format checkpoints.
@@ -210,6 +251,12 @@ class SeqPool:
     ones. A document's MSN and head change only in a pump that touches
     it, so `begin` files the previous pump's documents once, and a pick
     is O(1).
+
+    Checkpoints encode incrementally: every mirror write marks its
+    document dirty, and `encode_docs` re-encodes only those, reusing
+    every other document's encoded item from the last checkpoint.
+    Likewise the compaction sweep visits only the documents whose
+    column map gained a column or whose clients lost one.
     """
 
     def __init__(self, n_docs: int = 8, n_clients: int = 8,
@@ -261,6 +308,14 @@ class SeqPool:
         self._active_cold = 0
         self._cold: "OrderedDict[str, None]" = OrderedDict()
         self._warm: "OrderedDict[str, None]" = OrderedDict()
+        # Checkpoint encoding: each document's encoded item
+        # (`_encode_doc`; keyed in `docs`' order, since an entry is
+        # made wherever a document is) and the documents whose mirror
+        # entry changed since; the mirror entries the compaction sweep
+        # must visit (id -> entry).
+        self._items: Dict[str, Optional[bytes]] = {}
+        self._dirty: set = set()
+        self._compact: Dict[int, dict] = {}
         # Pool instrumentation (per-event counters here; occupancy
         # gauges are refreshed once per kernel pump by the core).
         m = get_registry()
@@ -310,6 +365,8 @@ class SeqPool:
             h = {"slot": None, "seq": 0, "min_seq": 0, "clients": {},
                  "cmap": {}, "t": 0}
             self.docs[doc_id] = h
+            self._items[doc_id] = None
+            self._dirty.add(doc_id)
         elif len(h["cmap"]) > 2 * len(h["clients"]) + 8:
             # Live compaction trigger (ROADMAP (c)): a high-churn doc
             # whose column map has outgrown its live clients reclaims
@@ -340,11 +397,14 @@ class SeqPool:
 
     def col_of_join(self, h: dict, cid) -> int:
         """The client's dense column, assigned on first join (columns
-        are per-doc-monotone, like the scalar per-doc client dict)."""
+        are per-doc-monotone, like the scalar per-doc client dict). A
+        new column makes the doc a compaction candidate: the join may
+        not take."""
         cmap = h["cmap"]
         col = cmap.get(cid)
         if col is None:
             col = cmap[cid] = len(cmap) + 1  # col 0 is scratch
+            self._compact[id(h)] = h
         return col
 
     def _victim(self) -> Optional[Tuple[str, str]]:
@@ -443,8 +503,9 @@ class SeqPool:
         so the device row matches the new layout before the next
         kernel call. Returns the number of columns reclaimed."""
         h = self.docs.get(doc_id)
-        if h is None:
-            return 0
+        return 0 if h is None else self._compact_entry(h)
+
+    def _compact_entry(self, h: dict) -> int:
         cmap = h["cmap"]
         live = h["clients"]
         reclaimed = len(cmap) - len(live)
@@ -462,8 +523,12 @@ class SeqPool:
 
     def compact_all(self) -> int:
         """Checkpoint-time sweep: compact every doc's column map (the
-        restart-free form of the checkpoint/restore compaction)."""
-        return sum(self.compact_doc(d) for d in list(self.docs))
+        restart-free form of the checkpoint/restore compaction). Only
+        the candidates can hold a departed client's column: a doc's map
+        outgrows its live clients only where it gained a column or its
+        clients lost one."""
+        candidates, self._compact = self._compact, {}
+        return sum(map(self._compact_entry, candidates.values()))
 
     def resident_docs(self) -> int:
         return len(self.slot_owner)
@@ -767,42 +832,57 @@ class SeqPool:
         h = self.docs[doc_id]
         h["clients"][cid] = [seq - 1, 0]
         h["seq"], h["min_seq"] = seq, msn
+        self._dirty.add(doc_id)
 
     def apply_leave(self, doc_id: str, cid: int, seq: int, msn: int) -> None:
         h = self.docs[doc_id]
         h["clients"].pop(cid, None)
         h["seq"], h["min_seq"] = seq, msn
+        self._dirty.add(doc_id)
+        self._compact[id(h)] = h
 
     def apply_op(self, doc_id: str, cid: int, seq: int, msn: int,
                  cseq: int, ref: int) -> None:
         h = self.docs[doc_id]
         h["clients"][cid] = [ref, cseq]
         h["seq"], h["min_seq"] = seq, msn
+        self._dirty.add(doc_id)
 
     def apply_stamp(self, doc_id: str, seq: int, msn: int) -> None:
         h = self.docs[doc_id]
         h["seq"], h["min_seq"] = seq, msn
+        self._dirty.add(doc_id)
+
+    def mark_dirty(self, doc_ids) -> None:
+        """Mark documents whose mirror entries the caller wrote itself
+        (the columnar emission's bulk update)."""
+        self._dirty.update(doc_ids)
 
     # -------------------------------------------------------- checkpoint
 
     def checkpoint_docs(self) -> dict:
         """Per-doc state in `DocumentSequencer.checkpoint()` format —
         scalar and kernel delis restore each other's checkpoints."""
-        return {
-            doc_id: {
-                "doc_id": doc_id,
-                "seq": h["seq"],
-                "min_seq": h["min_seq"],
-                "clients": {
-                    str(cid): {
-                        "ref_seq": rc[0], "client_seq": rc[1],
-                        "last_update": 0.0,
-                    }
-                    for cid, rc in h["clients"].items()
-                },
-            }
-            for doc_id, h in self.docs.items()
-        }
+        return {doc_id: _doc_state(doc_id, h)
+                for doc_id, h in self.docs.items()}
+
+    def encode_docs(self) -> Tuple[EncodedJson, int]:
+        """``json.dumps(checkpoint_docs())`` as an `EncodedJson` whose
+        parts are the documents' encoded items, and the number of
+        documents re-encoded: only those marked dirty since the last
+        call; every other item is the one encoded before."""
+        docs, items, dirty = self.docs, self._items, self._dirty
+        if len(items) != len(docs):
+            raise RuntimeError("a document entered the mirror unmarked")
+        for doc_id in dirty:
+            items[doc_id] = _encode_doc(doc_id, docs[doc_id])
+        self._dirty = set()
+        parts = [b"{"]
+        parts.extend(items.values())
+        if len(parts) > 1:
+            parts[1] = parts[1][2:]  # no separator before the first item
+        parts.append(b"}")
+        return EncodedJson(parts), len(dirty)
 
     def restore_docs(self, docs: Optional[dict]) -> None:
         for doc_id, st in (docs or {}).items():
@@ -816,6 +896,8 @@ class SeqPool:
                 "cmap": {cid: i + 1 for i, cid in enumerate(clients)},
                 "t": 0,
             }
+            self._items.setdefault(doc_id, None)
+            self._dirty.add(doc_id)
             self.note_client(len(clients) + 1)
 
 
@@ -1377,6 +1459,7 @@ class KernelDeliRole(_Role):
         self._recent: "OrderedDict[str, float]" = OrderedDict()
         self._pending: List[tuple] = []  # ("rec", off, dict) |
         #                                 ("cols", start_off, RecordBatch)
+        self._m_ckpt_docs: Optional[tuple] = None  # built at checkpoint
         # Blob pass-through is only legal when the output topic can
         # carry raw JSON bytes (a columnar sibling); a JSON out topic
         # needs decoded values for its json.dumps.
@@ -1387,10 +1470,31 @@ class KernelDeliRole(_Role):
     # ------------------------------------------------------------ state
 
     def snapshot_state(self) -> Any:
-        # Checkpoint time doubles as the column-compaction sweep
-        # (ROADMAP (c)): the snapshot never names departed clients.
+        """The per-doc map (`SeqPool.checkpoint_docs`) after the
+        column-compaction sweep (ROADMAP (c)): the snapshot never names
+        departed clients. `checkpoint` writes the same state from
+        `checkpoint_state`."""
         self.core.pool.compact_all()
         return self.core.pool.checkpoint_docs()
+
+    def checkpoint_state(self) -> Any:
+        """`snapshot_state` pre-encoded (`SeqPool.encode_docs`): the
+        sweep, then only the documents changed since the last
+        checkpoint are encoded again. Counts both on
+        ``deli_checkpoint_docs_{encoded,reused}_total{role}``."""
+        pool = self.core.pool
+        pool.compact_all()
+        enc, n = pool.encode_docs()
+        if self._m_ckpt_docs is None:
+            labels = self._metric_labels()
+            self._m_ckpt_docs = tuple(
+                self.metrics.counter(f"deli_checkpoint_docs_{k}_total",
+                                     **labels)
+                for k in ("encoded", "reused"))
+        self._m_ckpt_docs[0].inc(n)
+        self._m_ckpt_docs[1].inc(len(pool.docs) - n)
+        self.ckpt_docs_encoded = n
+        return enc
 
     def restore_state(self, state: Any) -> None:
         from .supervisor import unwrap_ranged_state
@@ -1850,8 +1954,11 @@ class KernelDeliRole(_Role):
                 # Mirror update from flat columns (last write wins per
                 # (doc, client) — order-equivalent within a span of
                 # plain ops, and spans run in stream order).
+                span_docs = rb.doc_idx[rows]
+                pool.mark_dirty([rb.docs[d] for d in
+                                 np.unique(span_docs).tolist()])
                 for d, cl, cs, rf, sq, mn in zip(
-                        rb.doc_idx[rows].tolist(),
+                        span_docs.tolist(),
                         rb.client[rows].tolist(),
                         rb.client_seq[rows].tolist(),
                         rb.ref_seq[rows].tolist(),

@@ -265,9 +265,18 @@ class _RangedMixin:
     # ------------------------------------------------------- state shape
 
     def snapshot_state(self):
+        return self._ranged_state(super().snapshot_state())
+
+    def checkpoint_state(self):
+        # The base role's state as it writes it (the kernel deli's
+        # pre-encoded map), inside the same wrapper.
+        base = super().checkpoint_state or super().snapshot_state
+        return self._ranged_state(base())
+
+    def _ranged_state(self, docs):
         st = {
             "__ranged__": 1,
-            "docs": super().snapshot_state(),
+            "docs": docs,
             "preds": {prid: p["off"] for prid, p in self._preds.items()
                       if p["off"] is not None and not p["done"]},
             "epoch": self.topo_epoch,

@@ -49,7 +49,7 @@ import os
 import subprocess
 import sys
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..utils.devices import (
     cpu_child_env,
@@ -199,6 +199,13 @@ class _Role:
     # ``byteOff`` — a hard lower bound for the catch-up tail seek,
     # stable under op-log truncation.
     _in_pos: Optional[int] = None
+    # A role that keeps its state pre-encoded provides
+    # `checkpoint_state()`: `snapshot_state` with `queue.EncodedJson`
+    # values in it, which `checkpoint` writes in its place (the same
+    # bytes). `ckpt_docs_encoded` is the documents its last call
+    # encoded anew (the `n` of the `<role>.checkpoint.snapshot` span).
+    checkpoint_state: Optional[Callable[[], Any]] = None
+    ckpt_docs_encoded: Optional[int] = None
 
     def _metric_labels(self) -> Dict[str, str]:
         """Metric label set: single-partition roles keep the historic
@@ -597,9 +604,10 @@ class _Role:
     def checkpoint(self) -> None:
         with self._sp_ckpt:
             t0 = time.perf_counter()
-            with self._sp_snapshot:
-                state = {"offset": self.offset,
-                         "state": self.snapshot_state()}
+            with self._sp_snapshot as snap:
+                state = {"offset": self.offset, "state": (
+                    self.checkpoint_state or self.snapshot_state)()}
+                snap.n = self.ckpt_docs_encoded
             n_bytes = self._durable(lambda: self.ckpt.save(
                 self.name, state, fence=self.fence, owner=self.owner,
             ))
@@ -618,9 +626,10 @@ class _Role:
         time or byte bound crossed), subject to the checkpoint-storm
         guard: a snapshot whose last write cost S seconds runs at most
         every ``S / ckpt_duty`` seconds, so huge states cannot turn
-        the cadence into a wall-clock sink (the 10k-doc deli snapshot
-        is tens of MB — every-pump writes would dominate the pipeline
-        end-to-end). Returns whether one was written."""
+        the cadence into a wall-clock sink (a 10k-doc deli's envelope
+        is tens of MB; the kernel deli re-encodes only the documents
+        changed since its last checkpoint, but writes and fsyncs the
+        whole envelope every time). Returns whether one was written."""
         if not self._ckpt_dirty:
             return False
         now = time.time()

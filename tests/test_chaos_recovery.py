@@ -15,13 +15,15 @@ Quick single-fault runs stay in tier-1; the full five-fault suite is
 
 from __future__ import annotations
 
+import json
 import os
 import time
 
 import pytest
 
 from fluidframework_tpu.server.queue import SharedFileTopic
-from fluidframework_tpu.server.supervisor import ServiceSupervisor
+from fluidframework_tpu.server.supervisor import DeliRole, \
+    ServiceSupervisor
 from fluidframework_tpu.testing.chaos import (
     ChaosConfig,
     build_workload,
@@ -168,6 +170,25 @@ def test_chaos_kill_torn_columnar_kernel_converges(tmp_path):
     ))
     _assert_converged(res)
     assert sum(res.restarts.values()) >= 4
+    # Recovery restored from the kernel deli's incremental checkpoints:
+    # the last one is the full encoding of its own content, and both
+    # deli implementations restore it to the same heads.
+    from fluidframework_tpu.server.deli_kernel import KernelDeliRole
+
+    with open(os.path.join(str(tmp_path), "checkpoints",
+                           "deli.ckpt.json"), "rb") as f:
+        raw = f.read()
+    env = json.loads(raw)
+    assert raw == json.dumps(env).encode()
+    docs = env["state"]["state"]
+    assert docs and all(st["seq"] > 0 for st in docs.values())
+    kernel = KernelDeliRole(str(tmp_path / "k"), owner="k", ttl_s=3600.0)
+    kernel.restore_state(docs)
+    assert kernel.snapshot_state() == docs
+    scalar = DeliRole(str(tmp_path / "s"), owner="s", ttl_s=3600.0)
+    scalar.restore_state(docs)
+    assert {d: st["seq"] for d, st in scalar.snapshot_state().items()} \
+        == {d: st["seq"] for d, st in docs.items()}
 
 
 def test_chaos_kill_every_role_exactly_once(tmp_path):
