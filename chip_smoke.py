@@ -16,7 +16,7 @@ exited before the next phase); every later phase runs in this process.
 - replay   the north-star stream (1,000,000 lagged ops, 1,024 clients,
            seed 7) through the compiled overlay engine; a 20k prefix is
            gated against the scalar oracle and the full-stream digest
-           against GOLDEN.json (`bench.py`'s helpers).
+           against GOLDEN.json.
 - serve    ContainerRuntime clients edit one SharedString concurrently
            over `LocalServer(deli_impl="kernel")`, converge, summarize,
            and cold-boot through `catchup`; then the kernel deli pool
@@ -57,6 +57,7 @@ from fluidframework_tpu.utils.devices import (  # noqa: E402  (no jax)
 )
 
 REPLAY_OPS, REPLAY_CLIENTS, GATE_OPS = 1_000_000, 1024, 20_000
+SEED, COLLAB_WINDOW, INITIAL_LEN = 7, 1024, 64
 GEOMETRY = {"chunk": 256, "window": 2048, "n_removers": 24}
 POOL_DOCS, POOL_CLIENTS = 10_000, 64
 SUMMARY_DOCS, SUMMARY_OPS_PER_DOC = 64, 1500
@@ -114,34 +115,97 @@ def phase_farm(work: str) -> None:
 # -------------------------------------------------------------- replay
 
 
-def phase_replay() -> None:
-    import bench
+def gen_stream(n_ops: int, n_clients: int):
+    """The north-star lagged op stream (seed 7), memoized under
+    ``<repo>/.stream_cache``."""
+    from fluidframework_tpu.testing.synthetic import generate_lagged_stream
 
+    return generate_lagged_stream(
+        n_ops, n_clients=n_clients, seed=SEED, window=COLLAB_WINDOW,
+        initial_len=INITIAL_LEN,
+        cache_dir=os.path.join(REPO, ".stream_cache"),
+    )
+
+
+def make_replica(stream, chunk: int, window: int, n_removers: int):
+    """A compiled (never interpreted) overlay replay replica."""
+    from fluidframework_tpu.core.overlay_replay import OverlayDeviceReplica
+
+    return OverlayDeviceReplica(
+        stream, initial_len=INITIAL_LEN, chunk_size=chunk, window=window,
+        n_removers=n_removers,
+    )
+
+
+def gate_prefix(gate_stream, **geometry) -> None:
+    """Replay `gate_stream` through the overlay engine and the scalar
+    oracle; fails unless the final texts are identical."""
+    from fluidframework_tpu.core.mergetree import replay_passive
+
+    gate = make_replica(gate_stream, **geometry)
+    # The incremental per-chunk path: the fused executable is
+    # shape-specialized to the main stream.
+    gate.replay(limit_chunks=gate.n_chunks)
+    gate.check_errors()
+    oracle = replay_passive(
+        gate_stream.as_messages(),
+        initial="".join(map(chr, gate_stream.text[:INITIAL_LEN])),
+    )
+    check(gate.get_text() == oracle.get_text(),
+          f"kernel/oracle divergence on the {len(gate_stream.seq)}-op "
+          f"gate prefix")
+
+
+def golden_check(replica, n_ops: int, n_clients: int):
+    """Compare `replica`'s full-stream final-state digest with
+    GOLDEN.json. Returns (digest, producer) on a match, None when the
+    golden was recorded for other stream params; fails on a
+    mismatch."""
+    from fluidframework_tpu.testing.digest import state_digest
+
+    with open(os.path.join(REPO, "GOLDEN.json")) as f:
+        golden = json.load(f)
+    params = {
+        "n_ops": n_ops, "n_clients": n_clients, "seed": SEED,
+        "initial_len": INITIAL_LEN, "lagged": True,
+        "window": COLLAB_WINDOW,
+    }
+    if golden.get("params") != params:
+        return None
+    producer = golden.get("chain", {}).get("full_engine", "?")
+    d = state_digest(replica.annotated_spans())
+    check(d == golden["digest"],
+          f"full-stream final state {d[:16]} diverges from the recorded "
+          f"{producer}-produced digest {golden['digest'][:16]}")
+    return d, producer
+
+
+def phase_replay() -> None:
     t0 = time.perf_counter()
-    stream = bench.gen_stream(REPLAY_OPS, REPLAY_CLIENTS)
-    gate = bench.gen_stream(GATE_OPS, REPLAY_CLIENTS)
+    stream = gen_stream(REPLAY_OPS, REPLAY_CLIENTS)
+    gate = gen_stream(GATE_OPS, REPLAY_CLIENTS)
     gen_s = time.perf_counter() - t0
 
     c0, t0 = compile_seconds(), time.perf_counter()
-    bench.gate_prefix(gate, "overlay", **GEOMETRY)
+    gate_prefix(gate, **GEOMETRY)
     gate_s = time.perf_counter() - t0
     gate_compile = compile_seconds() - c0
 
     c0, t0 = compile_seconds(), time.perf_counter()
-    first = bench.make_replica(stream, "overlay", **GEOMETRY)
+    first = make_replica(stream, **GEOMETRY)
     first.replay()
     first.check_errors()
     first_s = time.perf_counter() - t0
     replay_compile = compile_seconds() - c0
 
-    replica = bench.make_replica(stream, "overlay", **GEOMETRY)
+    replica = make_replica(stream, **GEOMETRY)
     replica.prepare()
     t0 = time.perf_counter()
     replica.replay()
     replica.check_errors()  # a fetch: the device has finished
     run_s = time.perf_counter() - t0
 
-    match = bench.golden_check(replica, REPLAY_OPS, REPLAY_CLIENTS)
+    match = golden_check(replica, REPLAY_OPS, REPLAY_CLIENTS)
     check(match is not None, "GOLDEN.json does not cover this stream")
     report("replay", ops=REPLAY_OPS, generate_s=gen_s,
            gate_ops=GATE_OPS, gate_s=gate_s, gate_compile_s=gate_compile,
@@ -203,7 +267,7 @@ def _pool_workload(work: str):
     """BASELINE config 5's raw topic (10,000 docs x 64 clients, one
     join + one op each) as a columnar log; returns (path, records)."""
     from fluidframework_tpu.server.columnar_log import make_topic
-    from fluidframework_tpu.testing.deli_bench import (
+    from fluidframework_tpu.testing.pipeline import (
         build_pipeline_workload,
     )
 
@@ -217,7 +281,7 @@ def _pool_workload(work: str):
 
 
 def _pool_identity(work: str) -> dict:
-    from fluidframework_tpu.testing.deli_bench import (
+    from fluidframework_tpu.testing.pipeline import (
         read_canonical,
         run_pipeline,
     )
@@ -259,7 +323,7 @@ def phase_summary(work: str) -> None:
         open_summary_store,
         read_catchup,
     )
-    from fluidframework_tpu.testing.deli_bench import (
+    from fluidframework_tpu.testing.pipeline import (
         build_mergetree_stream,
         drive_summarizer,
     )
@@ -323,7 +387,7 @@ def phase_summary(work: str) -> None:
 def phase_multichip(work: str) -> None:
     import __graft_entry__
     from fluidframework_tpu.parallel.mesh import shared_docs_mesh
-    from fluidframework_tpu.testing.deli_bench import (
+    from fluidframework_tpu.testing.pipeline import (
         read_canonical,
         run_pipeline,
     )
@@ -358,13 +422,25 @@ def phase_multichip(work: str) -> None:
            compile_s=compile_seconds() - c0, digests_identical=True)
 
 
+def require_tpus(n: int):
+    """The default backend's devices, after checking that the first
+    `n` are TPUs; exits non-zero with a message otherwise."""
+    import jax
+
+    devs = jax.devices()
+    if len(devs) < n or any(d.platform != "tpu" for d in devs[:n]):
+        sys.exit(f"chip_smoke: FAIL: needs {n} TPU device(s), found "
+                 f"{len(devs)} {devs[0].platform!r} "
+                 f"({devs[0].device_kind})")
+    return devs
+
+
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the multi-chip path (4 chips)")
     args = ap.parse_args(argv)
 
-    import bench
     from fluidframework_tpu.utils.devices import cpu_pinned, \
         use_compile_cache
 
@@ -377,7 +453,7 @@ def main(argv=None) -> None:
     try:
         if args.chips == 1:
             phase_farm(work)
-        devs = bench.require_tpus(args.chips)  # the first JAX import
+        devs = require_tpus(args.chips)  # the first JAX import
         track_compiles()
         report("device", platform=devs[0].platform,
                kind=devs[0].device_kind, count=len(devs),

@@ -6,8 +6,8 @@ annotate (local pending or sequenced remote), ack, MSN window +
 zamboni, perspective queries, reconnect regeneration — backed by the
 C++ segment list. Semantics are a faithful port of the oracle
 (differentially farm-tested, tests/test_native_engine.py); the win is
-the ~100x constant factor on the per-op document walks that dominate
-the interactive path (BENCH_DETAIL configs 1/3).
+the constant factor on the per-op document walks that dominate the
+interactive path.
 
 Property keys/values are interned to int32 on this side (`None`
 encodes as the PROP_DELETE sentinel, matching the reference's

@@ -29,9 +29,8 @@ path. `OverlayFoldReplica` is the summarizer-shaped driver:
   normalized to (UNIVERSAL_SEQ, NO_CLIENT), adjacent equal-semantic
   rows merged maximally) applied to the overlay state, so blob bytes
   and content-addressed handles are backend-invariant. The
-  differential gates (tests/test_device_plane.py,
-  `config15_device_plane`) hold the two backends byte-equal on every
-  host; `overlay_available` is the loud-fallback probe for hosts
+  differential gates (tests/test_device_plane.py) hold the two
+  backends byte-equal on every host; `overlay_available` is the loud-fallback probe for hosts
   where pallas cannot lower (CPU children run the interpreter via
   ``FLUID_FOLD_INTERPRET`` for correctness gates).
 """

@@ -41,8 +41,7 @@ class PermutationVector:
     """One axis's order: a merge-tree over handle items
     (reference PermutationVector, permutationvector.ts:151). Runs on
     the native C++ engine when available (core/native_engine.py — the
-    interactive hot path, BENCH_DETAIL config 3), falling back to the
-    Python oracle engine."""
+    interactive hot path), falling back to the Python oracle engine."""
 
     def __init__(self):
         from ..core.native_engine import make_merge_engine

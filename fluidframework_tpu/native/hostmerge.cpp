@@ -5,8 +5,8 @@
 // re-expression of reference packages/dds/merge-tree/src/mergeTree.ts
 // insertingWalk/markRangeRemoved/annotateRange and client.ts:98).
 // The reference runs this path in optimized JIT-compiled TypeScript;
-// the Python oracle is deliberately simple and ~100x too slow to
-// serve interactive clients (BENCH_DETAIL configs 1/3). This engine
+// the Python oracle is deliberately simple and too slow to serve
+// interactive clients. This engine
 // keeps the oracle's exact algorithm and data model — a document-
 // ordered segment list with perspective visibility — in C++, bound
 // via ctypes (core/native_engine.py), and is differentially farm-

@@ -198,8 +198,7 @@ class JournalConsumer:
 def fsync_file(f, kind: str = "topic") -> None:
     """One counted fsync: every durable-write fsync on the hot path
     routes through here so `topic_fsyncs_total{kind=}` reports the
-    per-record durability floor the fused-hop work attacks (the
-    bench's fsyncs-per-record evidence)."""
+    per-record durability floor the fused hop lowers."""
     os.fsync(f.fileno())
     from ..utils.metrics import get_registry
 
@@ -440,8 +439,8 @@ class SharedFileTopic:
 # ---------------------------------------------------------------------------
 
 # Kill switch: FLUID_DOORBELL=0 keeps every consumer on the pure poll
-# loop (the latency bench's baseline variant; also the escape hatch on
-# a platform where FIFOs misbehave).
+# loop (the baseline the doorbells are compared with; also the escape
+# hatch on a platform where FIFOs misbehave).
 DOORBELL_ENV = "FLUID_DOORBELL"
 
 _bell_seq = 0

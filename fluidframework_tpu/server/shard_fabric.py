@@ -41,11 +41,9 @@ instead of the single-partition pipeline PRs 1–4 grew:
   metrics (``role="deli", partition="3"``) that the supervisor scrape
   merges into one registry.
 
-`tools/shard_run.py` is the CLI; `testing.deli_bench.run_shard_bench`
-proves the aggregate-throughput scaling (bench_configs
-``config6_shard_scaling`` guards ≥1.5x at 4 partitions on ≥4-core
-hosts); `tools/partition_worker_main.py` is now a thin wrapper over
-`ShardWorker`.
+`tools/shard_run.py` is the CLI; `tools/partition_worker_main.py` is
+a thin wrapper over `ShardWorker`. No benchmark cell measures the
+fabric's aggregate throughput yet.
 """
 
 from __future__ import annotations

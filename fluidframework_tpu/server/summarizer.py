@@ -55,9 +55,8 @@ removed at/below ``msn_k`` is invisible to every later perspective and
 a row inserted at/below ``msn_k`` is visible to every later
 perspective — exactly the zamboni/compaction safety contract
 `KernelReplica.compact` rests on, applied at a recorded point. The
-differential gates (tests/test_summarizer.py, `config10_catchup`, the
-chaos summarizer-kill run) check it bit-for-bit via document-state
-digests.
+differential gates (tests/test_summarizer.py, the chaos
+summarizer-kill run) check it bit-for-bit via document-state digests.
 
 Readers: `SummaryIndex` (manifest tailer), `read_catchup` (nearest
 summary + op tail off the deltas topic), `SummaryReplica` (boots from
@@ -103,8 +102,7 @@ DEFAULT_SUMMARY_OPS = 256
 # speed against the vmapped replay is not measured on the chip yet).
 # Canonical row serialization is backend-invariant BY CONTRACT, so
 # blobs and content-addressed handles are bit-identical either way —
-# gated by `config15_device_plane` and tests/test_device_plane.py on
-# every host. Asking for "overlay" where pallas cannot lower (CPU host, no
+# gated by tests/test_device_plane.py on every host. Asking for "overlay" where pallas cannot lower (CPU host, no
 # interpreter requested) raises; the role never switches backends.
 FOLD_BACKEND_ENV = "FLUID_FOLD_BACKEND"
 # "1": run the overlay backend through the pallas INTERPRETER — the
@@ -1103,8 +1101,8 @@ def read_catchup(shared_dir: str, doc: str,
     summary exists yet — the tail is then the whole log).
 
     With a summary present the tail is read BACKWARD from the topic's
-    end (O(tail), so the join cost is flat in log length — the
-    config10 gate) on BOTH log formats: JSONL via the line scan below,
+    end (O(tail), so the join cost is flat in log length) on BOTH
+    log formats: JSONL via the line scan below,
     columnar via the frame-chaining scan
     (`columnar_log.tail_records_reverse`), which falls back to the
     forward skip from the manifest's `off` only when it cannot anchor

@@ -286,7 +286,7 @@ class _Role:
         # (queue.TopicDoorbell) with the SAME bounded timeout — an
         # append wakes the role immediately, and a missed ring only
         # costs the old poll latency. Created lazily on first idle so
-        # bench-driven roles (which never idle) register no FIFO.
+        # roles driven in-process (which never idle) register no FIFO.
         self._bell: Optional[TopicDoorbell] = None
         self._doorbell_ok = doorbells_enabled()
         # Wire tracing (off by default — see TRACE_WIRE_ENV) and the
@@ -1063,8 +1063,8 @@ class ScriptoriumBroadcasterRole(_Role):
     """The FUSED durable+broadcast hop: ONE supervised consumer plays
     both `ScriptoriumRole` and `BroadcasterRole`, so a record crosses
     deltas → durable → broadcast for one topic read, one process wake
-    and ~one fsync per batch instead of one of each PER STAGE (the
-    per-hop floor PR 9's open-loop bench exposed). The wire contract
+    and ~one fsync per batch instead of one of each PER STAGE. The
+    wire contract
     is unchanged — `durable` and `broadcast` carry exactly the records
     the split roles wrote — only the consumer topology fuses.
 
@@ -1466,9 +1466,10 @@ def serve_role(shared_dir: str, role: str, owner: str,
         # Heartbeat throttle: the default (0 = every step) is the
         # classic liveness contract, but a registry snapshot per
         # record is pure tail latency at high step rates — the
-        # latency bench runs its children at ~0.1s (still 20x inside
-        # the staleness threshold; forced heartbeats — degraded
-        # flags, fence rejections — always bypass the throttle).
+        # open-loop latency driver runs its children at ~0.1s (still
+        # 20x inside the staleness threshold; forced heartbeats —
+        # degraded flags, fence rejections — always bypass the
+        # throttle).
         r.hb_interval_s = hb_interval_s
     print(f"READY {r.name} {owner}", flush=True)
     while True:

@@ -15,7 +15,6 @@ from .chaos import (
     run_chaos,
     stream_digest,
 )
-from .scenarios import run_scenario_suite
 
 __all__ = [
     "ChaosConfig",
@@ -23,7 +22,6 @@ __all__ = [
     "FarmConfig",
     "random_op_for",
     "run_chaos",
-    "run_scenario_suite",
     "run_sharedstring_farm",
     "stream_digest",
 ]

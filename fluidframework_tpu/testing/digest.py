@@ -6,8 +6,9 @@ runs), so raw segment lists are not comparable. `normalize_spans`
 reduces any (content, props) span list to its canonical form —
 maximal runs of identical props — which is a pure function of the
 visible document state; `state_digest` hashes it. Used by the
-full-stream bit-identity gate (bench.py vs GOLDEN.json — the north
-star's "bit-identical final state" contract, BASELINE.json).
+full-stream bit-identity gate (`chip_smoke.py`'s replay phase vs
+GOLDEN.json — the north star's "bit-identical final state" contract,
+BASELINE.json).
 """
 
 from __future__ import annotations

@@ -1,11 +1,11 @@
 """Traffic-profile scenario layer: skewed, bursty, REAL-shaped load.
 
-Every bench in-tree so far spreads load evenly over many docs; real
-Fluid traffic is the opposite (SURVEY §S0: production load is
-dominated by joins and reads, and alfred exists precisely to absorb
-storms). This module composes OPEN-LOOP scenario primitives on top of
-the supervised farm / `deli_bench` machinery so the skewed shapes are
-first-class, guarded workloads:
+Even load over many docs is not what real Fluid traffic looks like
+(SURVEY §S0: production load is dominated by joins and reads, and
+alfred exists precisely to absorb storms). This module composes
+OPEN-LOOP scenario primitives on top of the supervised farm and the
+`testing.pipeline` drivers so the skewed shapes are first-class,
+tested workloads:
 
 - **hot-doc storm** (`run_hotdoc_storm`) — one viral document with
   thousands of writers plus a cold background mix, driven open-loop
@@ -54,11 +54,6 @@ The scenario CONTRACT (every primitive, every scale):
   scenarios, single-valued catch-up signatures / complete per-session
   delivery for read scenarios. A scenario cannot pass by shedding
   its own load.
-
-`run_scenario_suite` bundles all four at a common scale — the engine
-behind `tools/bench_configs.config13_scenarios` and
-`tools/bench_deli.py --scenarios`, whose per-scenario p99s feed the
-`bench_trend` ledger (lower-is-better `scenario_p99_ms` lines).
 """
 
 from __future__ import annotations
@@ -75,16 +70,14 @@ import time
 from typing import Any, Dict, List, Optional
 
 from .chaos import sequence_integrity, stream_digest
-from .deli_bench import _span_quantiles
+from .pipeline import _span_quantiles
 
 __all__ = [
     "run_hotdoc_storm",
     "run_read_swarm",
     "run_reconnect_stampede",
-    "run_scenario_suite",
     "run_tenant_mix",
     "run_week_of_traffic",
-    "scenario_p99s",
 ]
 
 
@@ -106,9 +99,8 @@ def _slowest(recorder, top: int = 5) -> List[dict]:
 def _fresh_metrics():
     """(registry, recorder, restore_fn): scenario-scoped metrics + a
     scenario-scoped flight recorder, swapped in process-wide so role
-    code constructed inside the scenario feeds THEM, not the suite's
-    shared instruments — the bench-isolation pattern `run_pipeline`
-    uses, extended to the recorder."""
+    code constructed inside the scenario feeds THEM, not the
+    process's shared instruments."""
     from ..utils import metrics as M
 
     reg = M.MetricsRegistry()
@@ -451,7 +443,7 @@ def run_reconnect_stampede(n_sessions: int = 2000, log_len: int = 20000,
         open_summary_store,
         read_catchup,
     )
-    from .deli_bench import drive_summarizer, build_mergetree_stream
+    from .pipeline import drive_summarizer, build_mergetree_stream
 
     scratch = work_dir or tempfile.mkdtemp(prefix="stampede-")
     reg, recorder, restore = _fresh_metrics()
@@ -1199,8 +1191,7 @@ def run_week_of_traffic(cycles: int = 4, hot_writers: int = 12,
       duplicate/skipped seqs.
 
     Returns the per-cycle usage table and ``retention_disk_mb`` (the
-    steady-state high-water mark, the bench_trend lower-is-better
-    ledger line)."""
+    steady-state high-water mark)."""
     if retention and cycles < 3:
         raise ValueError(
             "retention=True needs cycles >= 3: the bounded-disk gate "
@@ -1624,81 +1615,3 @@ def run_week_of_traffic(cycles: int = 4, hot_writers: int = 12,
             sup.stop()
         if work_dir is None:
             shutil.rmtree(scratch, ignore_errors=True)
-
-
-# ---------------------------------------------------------------------------
-# the suite
-# ---------------------------------------------------------------------------
-
-
-def scenario_p99s(suite: dict) -> Dict[str, Optional[float]]:
-    """{scenario: p99_ms} off a `run_scenario_suite` result — the
-    numbers the bench_trend ledger guards (lower is better)."""
-    return {
-        name: suite[name].get("scenario_p99_ms")
-        for name in ("storm", "stampede", "swarm", "tenant_mix")
-        if isinstance(suite.get(name), dict)
-    }
-
-
-def run_scenario_suite(scale: float = 1.0, deli_impl: str = "scalar",
-                       log_format: str = "json",
-                       swarm_sessions: int = 100_000,
-                       stampede_elastic_ranges: int = 0,
-                       work_dir: Optional[str] = None) -> dict:
-    """All four scenario primitives at a common `scale` (1.0 = the
-    full shapes: 2k-writer storm, 2k-session stampede, 100k-session
-    swarm, 4k-record tenant mix). Every scenario's convergence and
-    evidence gates run at EVERY scale — the asserts live inside the
-    primitives; a scaled-down suite still proves the contracts, it
-    only shrinks the load. Throughput/p99 honesty is per scenario
-    (the swarm loud-skips below its session/core bar; the ledger
-    gating of p99s is `tools/bench_configs.config13_scenarios`'
-    business)."""
-    suite: Dict[str, Any] = {
-        "metric": "scenario_suite",
-        "scale": scale,
-        "deli_impl": deli_impl,
-        "log_format": log_format,
-        "cores": os.cpu_count(),
-    }
-    suite["storm"] = run_hotdoc_storm(
-        n_writers=max(16, int(2000 * scale)),
-        cold_docs=max(2, int(32 * scale)),
-        rate_hz=max(50.0, 300.0 * scale),
-        duration_s=max(1.0, 4.0 * scale),
-        deli_impl=deli_impl, log_format=log_format,
-        work_dir=os.path.join(work_dir, "storm") if work_dir else None,
-    )
-    suite["stampede"] = run_reconnect_stampede(
-        n_sessions=max(24, int(2000 * scale)),
-        log_len=max(2048, int(20000 * scale)),
-        log_format=log_format,
-        # >= 2: the per-range elastic-summary variant (PR 13
-        # follow-up b) — the burst reads through the MERGED
-        # SummaryIndex over hash-range summaries-{rid} topics.
-        elastic_ranges=stampede_elastic_ranges,
-        work_dir=os.path.join(work_dir, "stampede")
-        if work_dir else None,
-    )
-    suite["swarm"] = run_read_swarm(
-        n_sessions=max(64, int(swarm_sessions * scale)),
-        work_dir=os.path.join(work_dir, "swarm") if work_dir else None,
-    )
-    suite["tenant_mix"] = run_tenant_mix(
-        records=max(180, int(4000 * scale)),
-        rate_hz=max(120.0, 400.0 * scale),
-        # ~2.8x headroom between the hot tenant's offered rate
-        # (hot_share * rate_hz) and the bucket: a loaded CI box that
-        # stretches the feed wall clock must still leave the hot
-        # tenant demonstrably over its budget.
-        rate_limit=max(30.0, 100.0 * scale),
-        log_format=log_format,
-        work_dir=os.path.join(work_dir, "mix") if work_dir else None,
-    )
-    suite["scenario_p99s"] = scenario_p99s(suite)
-    suite["gate"] = (
-        "per-scenario convergence digests + /slo + slow-op evidence "
-        "(asserted inside each primitive)"
-    )
-    return suite

@@ -10,8 +10,7 @@ from the environment alone, without starting a backend:
   virtual host devices (``--xla_force_host_platform_device_count``),
   which only exist if the flag precedes the first jax import — hence
   the subprocess helpers below. Emulation exercises correctness
-  (bit-identity across topologies), not throughput scaling:
-  `parity_skip_reason` renders the loud-skip text for that.
+  (bit-identity across topologies), not throughput scaling.
 - otherwise the host's accelerator is used in-process, and a run that
   finds fewer devices than it asked for fails; it never falls back to
   the CPU.
@@ -31,7 +30,7 @@ from __future__ import annotations
 import os
 import subprocess
 import sys
-from typing import Dict, List, Mapping, Optional, Tuple
+from typing import Dict, List, Mapping, Optional
 
 __all__ = [
     "COMPILE_CACHE_ENV",
@@ -41,11 +40,9 @@ __all__ = [
     "device_report",
     "forced_host_device_env",
     "holds_accelerator",
-    "parity_skip_reason",
     "run_forced_host_subprocess",
     "track_compiles",
     "use_compile_cache",
-    "visible_devices",
 ]
 
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
@@ -102,16 +99,6 @@ def forced_host_device_env(n_devices: int,
     )
     env["XLA_FLAGS"] = " ".join(flags)
     return env
-
-
-def visible_devices() -> Tuple[str, int]:
-    """(platform, count) of the default jax backend in THIS process.
-    Imports jax (and initializes the backend) — call only where that
-    is already paid for."""
-    import jax
-
-    devs = jax.devices()
-    return (devs[0].platform if devs else "none", len(devs))
 
 
 _DEVICE_REPORT: Optional[dict] = None
@@ -180,28 +167,6 @@ def track_compiles() -> None:
 def compile_seconds() -> Optional[float]:
     """Backend-compile seconds since `track_compiles`, else None."""
     return None if _COMPILE_S is None else _COMPILE_S[0]
-
-
-def parity_skip_reason(n_devices: int) -> Optional[str]:
-    """None when aggregate throughput scaling at `n_devices` can be
-    measured honestly on this host; else the loud-skip reason.
-
-    Honest means the devices are real accelerator chips, or virtual
-    host devices with at least one physical core each — N virtual
-    devices time-slicing fewer cores measure the OS scheduler, not
-    the sharding."""
-    platform, count = visible_devices()
-    if platform not in ("cpu", "none") and count >= n_devices:
-        return None
-    cores = os.cpu_count() or 1
-    if cores >= n_devices:
-        return None
-    return (
-        f"host has {cores} cores and no {n_devices}-device "
-        f"accelerator ({count} {platform} visible): {n_devices} "
-        f"forced-host devices would time-slice the cores and measure "
-        f"the scheduler, not multi-device scaling"
-    )
 
 
 def run_forced_host_subprocess(

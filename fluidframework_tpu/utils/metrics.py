@@ -15,7 +15,7 @@ Design constraints (the observability contract of ISSUE 3):
   hot paths cache instrument objects at construction and record
   per-pump aggregates, never per-record work on the kernel path. A
   `set_enabled(False)` switch swaps the default registry for a no-op
-  `NullRegistry` (the bench overhead guard measures against it).
+  `NullRegistry`.
 - **Deterministic-safe** — metrics are observational only: nothing
   here feeds back into sequencing, so stamped output and chaos golden
   digests are unchanged with instrumentation on.

@@ -308,46 +308,6 @@ def test_layer_check_clean():
     assert violations == [], "\n".join(violations)
 
 
-# ------------------------------------------------------ bench trend ledger
-
-
-def test_bench_trend_append_gate_and_skip(tmp_path):
-    """tools/bench_trend.py: results append to the ledger's trend
-    section; a >tolerance drop vs the best prior run fails; skipped
-    gate results are recorded but never gated (and never count as a
-    'best prior')."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        from bench_trend import append_and_gate, headline
-    finally:
-        sys.path.pop(0)
-
-    ledger = str(tmp_path / "ledger.json")
-    assert headline({"ops_per_sec": 10.0}) == ("ops_per_sec", 10.0)
-    assert headline({"note": "x"}) is None
-    r1 = {"metric": "m", "ops_per_sec": 1000.0, "unit": "records/s"}
-    assert append_and_gate(ledger, [r1]) == []
-    # Within tolerance: fine.
-    assert append_and_gate(ledger, [{"metric": "m",
-                                     "ops_per_sec": 850.0}]) == []
-    # Skipped results are recorded, not gated.
-    assert append_and_gate(ledger, [{"metric": "m", "ops_per_sec": 1.0,
-                                     "skipped": "small host"}]) == []
-    # A >20% drop vs the BEST prior (1000, not 850) fails loudly.
-    fails = append_and_gate(ledger, [{"metric": "m",
-                                      "ops_per_sec": 700.0}])
-    assert len(fails) == 1 and "regressed" in fails[0]
-    # The regression was still RECORDED.
-    import json as _json
-
-    with open(ledger) as f:
-        runs = _json.load(f)["trend"]["m"]
-    assert [r.get("value") for r in runs] == [1000.0, 850.0, 1.0, 700.0]
-    assert runs[2]["skipped"] is True
-    # A result with no headline appends ungated.
-    assert append_and_gate(ledger, [{"metric": "m2", "weird": 1}]) == []
-
-
 def test_metrics_report_renders_slow_ops(tmp_path):
     sys.path.insert(0, os.path.join(REPO, "tools"))
     try:

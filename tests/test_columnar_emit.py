@@ -345,9 +345,10 @@ def test_kernel_columnar_emit_v1_ingest(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def _drive_downstream(shared, roles, log_format, crash_step=None):
+def _drive_downstream(shared, roles, log_format, crash_step=None,
+                      deli_batch=512):
     deli = KernelDeliRole(str(shared), owner="d", ttl_s=3600.0,
-                          log_format=log_format)
+                          log_format=log_format, batch=deli_batch)
     idle = 0
     while idle < 3:
         idle = 0 if deli.step(idle_sleep=0.001) else idle + 1
@@ -406,6 +407,47 @@ def test_fused_hop_matches_split_pair(log_format, tmp_path):
     assert b1 == b2
     assert any(r.get("kind") == "nack" for r in b1)
     assert not any(r.get("kind") == "nack" for r in d1)
+
+
+def _topic_fsyncs(shared, log_format, make_roles):
+    """Topic fsyncs of one `_drive_downstream` run."""
+    reg = M.MetricsRegistry()
+    prev = M.set_registry(reg)
+    try:
+        _stage_raw(shared, log_format)
+        # Small deli pumps: one deltas append per raw frame, so the hop
+        # consumers append several times on either wire form.
+        _drive_downstream(shared, make_roles(shared), log_format,
+                          deli_batch=48)
+    finally:
+        M.set_registry(prev)
+    return reg.counter("topic_fsyncs_total", kind="topic").value
+
+
+@pytest.mark.parametrize("log_format", ["json", "columnar"])
+def test_fused_hop_fsyncs_fewer_than_split_pair(log_format, tmp_path):
+    """Over the same workload the fused consumer fsyncs the hop pair's
+    topics at most two thirds as often as the split pair: its
+    broadcast leg appends unfsynced. Counted, not timed."""
+    def split(s):
+        return [ScriptoriumRole(s, owner="s", ttl_s=3600.0,
+                                log_format=log_format, batch=37),
+                BroadcasterRole(s, owner="b", ttl_s=3600.0,
+                                log_format=log_format, batch=37)]
+
+    def fused(s):
+        return [ScriptoriumBroadcasterRole(s, owner="f", ttl_s=3600.0,
+                                           log_format=log_format,
+                                           batch=37)]
+
+    deli = _topic_fsyncs(str(tmp_path / "deli"), log_format,
+                         lambda s: [])
+    split_hop = _topic_fsyncs(str(tmp_path / "split"), log_format,
+                              split) - deli
+    fused_hop = _topic_fsyncs(str(tmp_path / "fused"), log_format,
+                              fused) - deli
+    assert fused_hop > 0
+    assert split_hop >= 1.5 * fused_hop, (split_hop, fused_hop)
 
 
 @pytest.mark.parametrize("log_format", ["json", "columnar"])

@@ -296,7 +296,7 @@ def test_local_server_sharded_end_to_end():
 
 def test_kernel_role_sharded_pipeline_matches_scalar(tmp_path):
     _need_devices(2)
-    from fluidframework_tpu.testing.deli_bench import (
+    from fluidframework_tpu.testing.pipeline import (
         build_pipeline_workload,
         run_pipeline,
         read_canonical,
@@ -405,33 +405,6 @@ def test_forced_host_subprocess_failure_is_loud():
 
     with pytest.raises(RuntimeError, match="rc=7"):
         run_forced_host_subprocess("raise SystemExit(7)", 2)
-
-
-def test_multichip_bench_rounds_docs_to_device_multiple():
-    # Regression: a doc count not divisible by every requested device
-    # count crashed the sharded child's device_put. The bench must
-    # round ONCE (lcm of all N) so every topology still sequences the
-    # identical workload and the digest gate stays meaningful.
-    from fluidframework_tpu.testing.deli_bench import run_multichip_bench
-
-    res = run_multichip_bench(devices=(1, 2), n_docs=3, ops_per_doc=2,
-                              n_clients=2, repeats=1)
-    assert res["docs"] == 4  # 3 rounded up to lcm(1, 2) * 2
-    assert len({r["digest"] for r in res["runs"]}) == 1
-
-
-def test_parity_skip_reason_shape():
-    import os
-
-    from fluidframework_tpu.utils.devices import parity_skip_reason
-
-    cores = os.cpu_count() or 1
-    assert parity_skip_reason(1) is None  # one device is always honest
-    big = parity_skip_reason(cores * 64)
-    # A count far past the host's cores must be refused with a reason
-    # naming the core deficit (unless real accelerators cover it, not
-    # the case under the conftest cpu pin).
-    assert big is not None and "cores" in big
 
 
 def test_devices_require_kernel_impl_everywhere(tmp_path):

@@ -364,7 +364,7 @@ def test_overlay_fold_canonical_rows_bit_identical(seed, cadence):
     canonical rows equal the vmapped kernel fold's byte-for-byte at
     EVERY emission point — same blob bytes, same content-addressed
     handles, restart-stable across either engine."""
-    from fluidframework_tpu.testing.deli_bench import (
+    from fluidframework_tpu.testing.pipeline import (
         build_mergetree_stream,
     )
 
@@ -384,7 +384,7 @@ def test_boot_overlay_roundtrip_idempotent():
         _boot_mergetree,
         _canonical_rows,
     )
-    from fluidframework_tpu.testing.deli_bench import (
+    from fluidframework_tpu.testing.pipeline import (
         build_mergetree_stream,
     )
 
@@ -402,7 +402,7 @@ def test_stacked_fold_group_over_plane_bit_identical():
     the pool, dummy-padded to the mesh size) both byte-identical to
     the unplaced single-doc folds."""
     _need_devices(4)
-    from fluidframework_tpu.testing.deli_bench import (
+    from fluidframework_tpu.testing.pipeline import (
         build_mergetree_stream,
     )
 
@@ -472,7 +472,7 @@ def test_summarizer_role_overlay_backend_identical_handles(tmp_path):
     content-addressed handles — as the kernel-backend role over the
     same stream (and the resolved-backend gauge says which engine
     actually ran)."""
-    from fluidframework_tpu.testing.deli_bench import (
+    from fluidframework_tpu.testing.pipeline import (
         build_mergetree_stream,
     )
 

@@ -13,8 +13,7 @@ higher) fences reject the pre-split owner, the exactly-once ``inOff``
 scan closes the durable gap, and the merged per-doc stream never
 duplicates or skips a sequence number while N changes mid-run. The
 multi-process supervised form under seeded faults lives in
-tests/test_chaos_recovery.py; the rebalance-cost guard in
-bench_configs ``config8_rebalance``.
+tests/test_chaos_recovery.py.
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """Tail-latency SLO layer (ISSUE 9): topic doorbells (event-driven
 consumer wakeups with poll fallback), the /slo + /traces endpoints,
-the slow-op flight recorder, and the open-loop latency bench's
+the slow-op flight recorder, and the open-loop latency driver's
 trace/quantile correctness contract.
 
 Determinism is the standing constraint: doorbells are advisory-only
@@ -258,20 +258,19 @@ def test_traces_endpoint_defaults_to_process_recorder():
 
 
 # ---------------------------------------------------------------------------
-# the open-loop bench's correctness contract (scaled down; the
-# p99-improvement judgment is bench_configs.config9_latency)
+# the open-loop latency driver's correctness contract
 # ---------------------------------------------------------------------------
 
 
 def test_latency_variant_traces_quantiles_and_slow_ops(tmp_path):
-    """One doorbell variant at low rate: every op exactly-once in
+    """One open-loop run at low rate: every op exactly-once in
     broadcast, monotone spans, the child-reported histogram
     bucket-identical to the wire spans (asserted inside), and the
     slow-op spans naming real ops."""
-    from fluidframework_tpu.testing.deli_bench import _run_latency_variant
+    from fluidframework_tpu.testing.pipeline import _run_latency_variant
 
     res = _run_latency_variant(
-        str(tmp_path), True, rate_hz=50.0, duration_s=1.2,
+        str(tmp_path), rate_hz=50.0, duration_s=1.2,
         n_docs=2, n_clients=2, ttl_s=0.75, timeout_s=60.0,
     )
     assert res["records"] == 60 + res["lead_in"]

@@ -702,7 +702,7 @@ def test_chaos_kill_mid_truncate_and_gc_converges():
 
 
 # ---------------------------------------------------------------------------
-# the churn gate, scaled (the config14 shape)
+# the churn gate, scaled
 # ---------------------------------------------------------------------------
 
 

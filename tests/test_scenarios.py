@@ -231,8 +231,8 @@ def test_admit_to_stamp_monotone_and_observed(tmp_path, monkeypatch):
 def test_admit_to_stamp_kernel_deli_parity(tmp_path, monkeypatch):
     """The KERNEL deli threads the admission stamp too (the plan
     tuple carries adm_ts next to sub_ts): same wire shape, same
-    histogram, one clock read per flush — the config12 kernel+ingress
-    topology must not silently lose the stage the scalar role has."""
+    histogram, one clock read per flush — the kernel+ingress topology
+    must not silently lose the stage the scalar role has."""
     from fluidframework_tpu.server.columnar_log import make_topic
     from fluidframework_tpu.server.supervisor import resolve_role_class
 
@@ -452,7 +452,7 @@ def test_fabric_traces_and_partition_slo_over_http(tmp_path):
         ShardRouter,
         spread_doc_names,
     )
-    from fluidframework_tpu.testing.deli_bench import (
+    from fluidframework_tpu.testing.pipeline import (
         build_pipeline_workload,
     )
 

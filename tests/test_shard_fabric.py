@@ -8,8 +8,7 @@ routing (boxcar-aware), emergent lease balance across workers
 (membership change IS the rebalance trigger), fenced handoff with
 exactly-once resumption, per-partition metric labels, and the
 `LocalServer(n_partitions=)` in-proc face. The multi-process
-supervised form under faults lives in tests/test_chaos_recovery.py;
-throughput scaling in bench_configs ``config6_shard_scaling``.
+supervised form under faults lives in tests/test_chaos_recovery.py.
 """
 
 from __future__ import annotations
@@ -555,23 +554,51 @@ def test_localserver_rejects_bad_n_partitions():
 
 
 # ---------------------------------------------------------------------------
-# shard bench machinery (tiny smoke; the real guard is bench_configs)
+# partition count never changes a document's order
 # ---------------------------------------------------------------------------
 
 
-def test_shard_bench_gates_bit_identity(tmp_path):
-    from fluidframework_tpu.testing.deli_bench import run_shard_bench
-
-    res = run_shard_bench(
-        n_docs=24, n_clients=2, ops_per_client=2, partitions=(1, 2),
-        deli_impl="scalar", log_format="columnar", batch=4096,
-        work_dir=str(tmp_path),
+def _drain_partitions(work, workload, n_partitions):
+    """Split `workload` by `record_partition` into one columnar raw
+    topic per partition, drain each through the scalar deli, and
+    return the outputs merged per document."""
+    from fluidframework_tpu.testing.pipeline import (
+        _canonical_by_doc,
+        run_pipeline,
     )
-    assert res["gate"] == "bit-identical across partitions"
-    assert res["runs"][0]["partitions"] == 1
-    assert res["runs"][1]["partitions"] == 2
-    assert sum(res["runs"][1]["per_partition_records"]) == res["records"]
-    assert res["speedup"] > 0
+
+    shards = [[] for _ in range(n_partitions)]
+    for rec in workload:
+        shards[record_partition(rec, n_partitions)].append(rec)
+    assert all(shards), "a partition got no records"
+    out_paths = []
+    for p, recs in enumerate(shards):
+        pdir = os.path.join(work, f"P{n_partitions}", f"p{p}")
+        os.makedirs(pdir)
+        raw_path = os.path.join(pdir, "rawdeltas.jsonl")
+        make_topic(raw_path, "columnar").append_many(recs)
+        out_paths.append(run_pipeline("scalar", raw_path, pdir,
+                                      log_format="columnar")["out_path"])
+    return _canonical_by_doc(out_paths)
+
+
+@pytest.mark.parametrize("n_partitions", [2, 4])
+def test_partition_count_keeps_each_documents_order(n_partitions,
+                                                    tmp_path):
+    """The same workload split over P partitions sequences every
+    document exactly as one partition does: same seqs, MSNs, nacks
+    and contents, record for record."""
+    from fluidframework_tpu.testing.pipeline import build_pipeline_workload
+
+    workload = build_pipeline_workload(
+        24, 2, 2, doc_names=spread_doc_names(24, 4)
+    )
+    single = _drain_partitions(str(tmp_path), workload, 1)
+    assert len(single) == 24
+    assert sum(map(len, single.values())) == len(workload)
+    assert _drain_partitions(str(tmp_path), workload,
+                             n_partitions) == single
+
 
 # ---------------------------------------------------------------------------
 # per-partition downstream stages (static fabric, front-door PR)

@@ -29,7 +29,7 @@ from fluidframework_tpu.server.summarizer import (
     open_summary_store,
     read_catchup,
 )
-from fluidframework_tpu.testing.deli_bench import build_mergetree_stream
+from fluidframework_tpu.testing.pipeline import build_mergetree_stream
 from fluidframework_tpu.testing.farm import FarmConfig, run_sharedstring_farm
 
 

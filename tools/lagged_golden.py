@@ -16,8 +16,9 @@ Verification chain (each link independently farm-tested):
    tools/overlay_golden-style runs, or the pure oracle extending past
    its prefix) can be merged via --merge-log to cross-check stages
    from a second implementation family.
-4. bench.py requires the pallas overlay engine's full-stream digest to
-   equal the recorded digest (the north-star bit-identity contract).
+4. `chip_smoke.py` requires the pallas overlay engine's full-stream
+   digest to equal the recorded digest (the north-star bit-identity
+   contract).
 
 Usage: python tools/lagged_golden.py [n_ops] [oracle_prefix]
        python tools/lagged_golden.py --merge-log LOG TAG

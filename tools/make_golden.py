@@ -1,4 +1,4 @@
-"""Record the golden final-state digest for the standard bench stream.
+"""Record the golden final-state digest for the standard replay stream.
 
 Verification chain (each link independently tested):
 
@@ -10,8 +10,8 @@ Verification chain (each link independently tested):
    kernel, an implementation independent of the pallas kernel) must
    match the oracle bit-for-bit on that prefix, then replays the FULL
    stream to produce the recorded digest.
-3. bench.py requires the pallas engine's full-stream digest to equal
-   the recorded scan digest (GOLDEN.json), closing the round-1 gap
+3. `chip_smoke.py` requires the pallas engine's full-stream digest to
+   equal the recorded digest (GOLDEN.json), closing the round-1 gap
    where identity was only gated on a 20k prefix.
 
 The stream is deterministic (seeded); params ride the file and are
