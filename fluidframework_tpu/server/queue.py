@@ -994,12 +994,14 @@ class FencedCheckpointStore:
 
     The reference's deli checkpoints to Mongo with the partition
     epoch as the fencing token; here each key is a JSON file
-    ``{"fence", "owner", "state"}`` and `save` is a read-gate-write
-    critical section under an OS file lock. A writer carrying a fence
-    lower than the stored one — or an equal fence under a different
-    owner than the one that first bound it — gets `FencedError`, so a
-    deposed lease holder can never roll a successor's checkpoint back
-    (the exactly-once recovery contract of ISSUE round 1).
+    ``{"fence", "owner", "state"}``. `save` encodes the envelope, then
+    flushes it in a read-gate-write critical section under an OS file
+    lock; a role that writes off its pump thread calls the two apart
+    (`encode` on the pump, `flush` on its writer). A writer carrying a
+    fence lower than the stored one — or an equal fence under a
+    different owner than the one that first bound it — gets
+    `FencedError`, so a deposed lease holder can never roll a
+    successor's checkpoint back (the exactly-once recovery contract).
 
     The gate reads only the stored envelope's head (`_HEAD_BYTES`),
     where `save` puts the fence and owner ahead of the state; an
@@ -1061,20 +1063,31 @@ class FencedCheckpointStore:
         except (OSError, ValueError):
             return None
 
-    def save(self, key: str, state: Any, fence: int,
-             owner: Optional[str] = None,
-             lock_timeout_s: Optional[float] = None) -> int:
-        """Fenced write; returns the serialized envelope size in bytes
-        (the checkpoint-bytes metric's source). The file holds
-        ``json.dumps({"fence", "owner", "state"})``: an `EncodedJson`
-        value anywhere in `state` is written as its parts, the text
-        around it as `json.dumps` writes it, chunk by chunk (the
-        `checkpoint.encode` phase then encodes only that text), so a
-        role that keeps its state pre-encoded writes the same bytes
-        without one envelope-sized string."""
+    def encode(self, key: str, state: Any, fence: int,
+               owner: Optional[str] = None) -> List[bytes]:
+        """The envelope ``{"fence", "owner", "state"}`` as the byte
+        chunks `flush` writes: ``json.dumps`` of it, an `EncodedJson`
+        value anywhere in `state` as its parts and the text around it
+        chunk by chunk (the `checkpoint.encode` phase then encodes only
+        that text), so a role that keeps its state pre-encoded writes
+        the same bytes without one envelope-sized string. The chunks
+        are bytes: nothing done to `state` afterwards reaches them."""
+        _, sp_encode, _, _ = self._instruments_of(key)
+        with sp_encode:
+            return _encode_envelope(
+                {"fence": fence, "owner": owner, "state": state})
+
+    def flush(self, key: str, payload: List[bytes], fence: int,
+              owner: Optional[str] = None,
+              lock_timeout_s: Optional[float] = None) -> int:
+        """Fenced write of `payload`, an envelope that `encode` made
+        with the same `fence` and `owner`; returns its size in bytes
+        (the checkpoint-bytes metric's source). Lock, fence check, then
+        the chunks written, fsynced and moved into place, so a reader
+        finds the previous envelope or this one, whole."""
         import fcntl
 
-        sp_fence, sp_encode, sp_write, full_loads = self._instruments_of(key)
+        sp_fence, _, sp_write, full_loads = self._instruments_of(key)
         lock_path = self._path(key) + ".lock"
         with open(lock_path, "a+") as lk, contextlib.ExitStack() as gate:
             # The fence phase: lock wait, the stored envelope's head
@@ -1105,10 +1118,6 @@ class FencedCheckpointStore:
                                  f"checkpoint {key!r}")
                 gate.close()
                 tmp = self._path(key) + f".tmp.{os.getpid()}"
-                with sp_encode:
-                    payload = _encode_envelope(
-                        {"fence": fence, "owner": owner, "state": state}
-                    )
                 with sp_write:
                     check_disk_fault("checkpoint")
                     with open(tmp, "wb", buffering=_WRITE_BUFFER) as f:
@@ -1119,6 +1128,15 @@ class FencedCheckpointStore:
             finally:
                 fcntl.flock(lk.fileno(), fcntl.LOCK_UN)
         return sum(map(len, payload))
+
+    def save(self, key: str, state: Any, fence: int,
+             owner: Optional[str] = None,
+             lock_timeout_s: Optional[float] = None) -> int:
+        """Fenced write of `state`: `encode`, then `flush`. The file
+        holds ``json.dumps({"fence", "owner", "state"})``; returns its
+        size in bytes."""
+        return self.flush(key, self.encode(key, state, fence, owner),
+                          fence, owner, lock_timeout_s)
 
 
 HASH_SPACE = 1 << 32  # the document hash ring [0, 2^32)

@@ -745,8 +745,8 @@ class SummarizerRole(_Role):
         self._unpin()
         return n
 
-    def checkpoint(self) -> None:
-        super().checkpoint()
+    def _take_checkpoint(self, background: bool) -> None:
+        super()._take_checkpoint(background)
         # Recovery and pred drains append outside `_append_outputs`;
         # both checkpoint right after, so the pin never outlives the
         # round however the manifests landed.

@@ -48,6 +48,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -170,6 +171,60 @@ def canonical_record(rec: dict) -> dict:
 # ---------------------------------------------------------------------------
 
 
+class _CheckpointFlush:
+    """One cadence checkpoint's flush on its own writer thread: the
+    lock, fence check, write, fsync and replace of an envelope its role
+    encoded on the pump (`FencedCheckpointStore.flush`), under the same
+    storage-fault retry budget as an inline write. The writer records
+    a `degraded` change and leaves its export to the pump; its result
+    or error is read by the pump (`_Role._reap_checkpoint`)."""
+
+    def __init__(self, role: "_Role", payload: List[bytes]):
+        self.role = role
+        self.payload = payload
+        self.fence, self.owner = role.fence, role.owner
+        self.pump_s = self.flush_s = self.t_done = 0.0
+        self.n_bytes = 0
+        self.marked_degraded = False
+        self.error: Optional[Exception] = None
+        self.thread = threading.Thread(
+            target=self._run, name=f"{role.name}.checkpoint.flush",
+            daemon=True)
+
+    def start(self, pump_s: float) -> None:
+        self.pump_s = pump_s
+        self.thread.start()
+
+    def _degraded(self, flag: bool) -> None:
+        role = self.role
+        role.degraded = self.marked_degraded = flag
+        role._m_degraded.set(1.0 if flag else 0.0)
+        role._ckpt_news = True  # after the flag: the pump exports both
+
+    def _note_retry(self, attempt, exc, delay) -> None:
+        self.role._m_disk_retries.inc()
+        self._degraded(True)
+
+    def _flush(self) -> int:
+        role = self.role
+        with role._sp_ckpt_flush:
+            return role.ckpt.flush(role.name, self.payload,
+                                   fence=self.fence, owner=self.owner)
+
+    def _run(self) -> None:
+        t0 = time.perf_counter()
+        try:
+            self.n_bytes = retry_durable(self._flush,
+                                         on_retry=self._note_retry)
+            if self.marked_degraded:
+                self._degraded(False)  # recovery is news too
+        except Exception as exc:  # re-raised on the pump
+            self.error = exc
+        self.payload = None
+        self.flush_s = time.perf_counter() - t0
+        self.t_done = time.time()
+
+
 class _Role:
     """One supervised lambda: fenced lease + heartbeat + exactly-once
     consume/transform/append loop over shared file topics."""
@@ -242,11 +297,12 @@ class _Role:
         `ckpt_duty` is the checkpoint-STORM guard: once state grows to
         where one snapshot costs S seconds (a 10k-doc deli checkpoint
         runs to tens of MB), a cadence that fires every pump would
-        spend most of the wall clock checkpointing — so a snapshot
-        costing S runs at most every ``S / ckpt_duty`` seconds,
-        bounding checkpoint work to that fraction of wall time however
-        large the state gets. Recovery granularity widens with it;
-        correctness does not (the inOff scan replays any gap).
+        spend most of the wall clock checkpointing — so a checkpoint
+        whose snapshot and flush cost S is followed by the next at most
+        every ``S / ckpt_duty`` seconds, bounding checkpoint work to
+        that fraction of wall time however large the state gets.
+        Recovery granularity widens with it; correctness does not (the
+        inOff scan replays any gap).
         Explicit every-step mode (``ckpt_interval_s=0``) bypasses the
         guard."""
         self.shared_dir = shared_dir
@@ -307,6 +363,11 @@ class _Role:
         self._ckpt_last_t = time.time()
         self._ckpt_last_s = 0.0
         self._ckpt_pending_bytes = 0
+        # The cadence checkpoint's flush in flight on its writer thread,
+        # and whether that writer changed `degraded` since the pump last
+        # exported it (the heartbeat file has one writer: the pump).
+        self._ckpt_job: Optional[_CheckpointFlush] = None
+        self._ckpt_news = False
         self._hb_t = 0.0
         from ..utils.metrics import get_registry, span
 
@@ -320,7 +381,16 @@ class _Role:
         self._m_ckpt_bytes = m.counter(
             "checkpoint_bytes_total", **labels
         )
+        # The pump's time in a checkpoint (the stall), and the flush
+        # each cadence checkpoint leaves to its writer thread.
         self._m_ckpt_ms = m.histogram("checkpoint_ms", **labels)
+        self._m_ckpt_flush_ms = m.histogram("checkpoint_flush_ms", **labels)
+        self._m_ckpt_async = m.counter(
+            "checkpoint_flushes_async_total", **labels
+        )
+        self._m_ckpt_deferred = m.counter(
+            "checkpoint_flush_deferred_total", **labels
+        )
         self._m_fenced = m.counter("fence_rejections_total", **labels)
         self._m_disk_retries = m.counter("disk_retries_total", **labels)
         self._m_degraded = m.gauge("role_degraded", **labels)
@@ -328,11 +398,12 @@ class _Role:
         # the pump is the root of every step that reaches the poll.
         self.span_prefix = self.role_base or self.name
         (self._sp_pump, self._sp_poll, self._sp_flush, self._sp_append,
-         self._sp_ckpt, self._sp_snapshot, self._sp_heartbeat,
-         self._sp_idle) = (
+         self._sp_ckpt, self._sp_snapshot, self._sp_ckpt_flush,
+         self._sp_heartbeat, self._sp_idle) = (
             span(f"{self.span_prefix}.{phase}", **labels)
             for phase in ("pump", "poll", "flush", "append", "checkpoint",
-                          "checkpoint.snapshot", "heartbeat", "idle"))
+                          "checkpoint.snapshot", "checkpoint.flush",
+                          "heartbeat", "idle"))
 
     # ------------------------------------------------------------ state
 
@@ -602,34 +673,95 @@ class _Role:
         return done
 
     def checkpoint(self) -> None:
+        """Write a checkpoint now, on this thread: wait for the flush in
+        flight (re-raising its error), then snapshot and save. Recovery,
+        handoffs and every-step mode take this path."""
+        self.wait_checkpoint()
+        self._take_checkpoint(background=False)
+
+    def _take_checkpoint(self, background: bool) -> None:
+        """Snapshot the state at this offset and write it: inline, or
+        with `background` encoded here and flushed by a writer thread
+        (`_CheckpointFlush`), the pump stalling for the snapshot and
+        its encoding alone."""
         with self._sp_ckpt:
             t0 = time.perf_counter()
             with self._sp_snapshot as snap:
                 state = {"offset": self.offset, "state": (
                     self.checkpoint_state or self.snapshot_state)()}
                 snap.n = self.ckpt_docs_encoded
-            n_bytes = self._durable(lambda: self.ckpt.save(
-                self.name, state, fence=self.fence, owner=self.owner,
-            ))
+            if background:
+                payload = self.ckpt.encode(self.name, state,
+                                           fence=self.fence, owner=self.owner)
+                self._ckpt_job = _CheckpointFlush(self, payload)
+            else:
+                n_bytes = self._durable(lambda: self.ckpt.save(
+                    self.name, state, fence=self.fence, owner=self.owner,
+                ))
             with self._sp_snapshot:
                 del state  # freeing the snapshot's dicts is its cost too
-            self._ckpt_last_s = time.perf_counter() - t0
+            self._ckpt_dirty = False
+            self._ckpt_pending_bytes = 0
+            pump_s = time.perf_counter() - t0
+        self._m_ckpt_ms.observe(pump_s * 1000.0)
+        if background:
+            # Until the flush lands, the cadence counts from here.
+            self._ckpt_last_t = time.time()
+            self._ckpt_job.start(pump_s)
+        else:
+            self._checkpoint_landed(pump_s, n_bytes, time.time())
+
+    def _checkpoint_landed(self, total_s: float, n_bytes: int,
+                           t_done: float) -> None:
+        self._ckpt_last_s = total_s
+        self._ckpt_last_t = t_done
         self._m_ckpt_writes.inc()
         self._m_ckpt_bytes.inc(n_bytes)
-        self._m_ckpt_ms.observe(self._ckpt_last_s * 1000.0)
-        self._ckpt_dirty = False
-        self._ckpt_pending_bytes = 0
-        self._ckpt_last_t = time.time()
+
+    def _reap_checkpoint(self) -> None:
+        """The pump's side of the writer: export a `degraded` change the
+        writer made, and once its flush has ended, account for it or
+        re-raise its error (a `FencedError` takes the pump's fenced
+        exit; an `OSError` past the retry budget fails the role, as a
+        failed inline write does)."""
+        if self._ckpt_news:
+            self._ckpt_news = False
+            self.heartbeat(force=True)
+        job = self._ckpt_job
+        if job is None or job.thread.is_alive():
+            return
+        self._ckpt_job = None
+        if job.error is not None:
+            self._ckpt_dirty = True  # what it held is not durable
+            raise job.error
+        self._m_ckpt_async.inc()
+        self._m_ckpt_flush_ms.observe(job.flush_s * 1000.0)
+        self._checkpoint_landed(job.pump_s + job.flush_s, job.n_bytes,
+                                job.t_done)
+
+    def wait_checkpoint(self) -> None:
+        """Block until the flush in flight, if any, has ended, and reap
+        it here (its error re-raised). Paths that end a role cleanly,
+        and readers of its checkpoint, call this first."""
+        job = self._ckpt_job
+        if job is not None:
+            job.thread.join()
+        self._reap_checkpoint()
 
     def maybe_checkpoint(self) -> bool:
-        """Write a checkpoint iff the cadence says so (dirty AND the
-        time or byte bound crossed), subject to the checkpoint-storm
-        guard: a snapshot whose last write cost S seconds runs at most
-        every ``S / ckpt_duty`` seconds, so huge states cannot turn
-        the cadence into a wall-clock sink (a 10k-doc deli's envelope
-        is tens of MB; the kernel deli re-encodes only the documents
-        changed since its last checkpoint, but writes and fsyncs the
-        whole envelope every time). Returns whether one was written."""
+        """Take a checkpoint iff the cadence says so (dirty AND the time
+        or byte bound crossed), subject to the checkpoint-storm guard: a
+        checkpoint whose snapshot and flush cost S seconds is followed
+        by the next at most every ``S / ckpt_duty`` seconds, so huge
+        states cannot turn the cadence into a wall-clock sink (a
+        10k-doc deli's envelope is tens of MB). The pump takes the
+        snapshot and encodes it; a writer thread flushes it (lock, fence
+        check, write, fsync, replace). One flush at a time: a checkpoint
+        due while one is in flight is deferred (counted), never queued,
+        so an older snapshot cannot land after a newer one. Every-step
+        mode (``ckpt_interval_s=0``) writes inline. Returns whether one
+        was taken."""
+        self._reap_checkpoint()
         if not self._ckpt_dirty:
             return False
         now = time.time()
@@ -643,7 +775,13 @@ class _Role:
             # Storm guard (ckpt_interval_s=0 — every-step mode — and
             # ckpt_duty=0 — guard disabled — both bypass it).
             return False
-        self.checkpoint()
+        if self.ckpt_interval_s <= 0:
+            self.checkpoint()
+            return True
+        if self._ckpt_job is not None:
+            self._m_ckpt_deferred.inc()
+            return False
+        self._take_checkpoint(background=True)
         return True
 
     def step(self, idle_sleep: float = 0.01) -> int:

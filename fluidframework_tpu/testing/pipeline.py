@@ -116,6 +116,7 @@ def run_pipeline(impl: str, raw_path: str, out_dir: str,
         role.offset = reader.next_line
         role._ckpt_dirty = True
         role.maybe_checkpoint()
+    role.wait_checkpoint()
     return {"seconds": time.perf_counter() - t0, "out_path": out_path}
 
 

@@ -418,6 +418,7 @@ def test_checkpoint_cadence_time_byte_bounds(tmp_path, fresh_registry):
     raw.append({"kind": "op", "doc": "d", "client": 1, "clientSeq": 6,
                 "refSeq": 0, "contents": {"i": 6}})
     role.step()
+    role.wait_checkpoint()  # the cadence flushes on the writer thread
     assert writes.value == baseline + 1
     assert not role._ckpt_dirty
     # Time bound: interval 0 == the seed's every-step policy.
@@ -452,6 +453,7 @@ def test_checkpoint_cadence_idle_flush(tmp_path, fresh_registry):
     writes = fresh_registry.counter("checkpoint_writes_total", role="deli")
     raw.append({"kind": "join", "doc": "d", "client": 1})
     role.step()
+    role.wait_checkpoint()  # the cadence flushes on the writer thread
     before = writes.value
     if not role._ckpt_dirty:
         # The batch step itself crossed the 50ms interval and flushed;
@@ -459,10 +461,12 @@ def test_checkpoint_cadence_idle_flush(tmp_path, fresh_registry):
         raw.append({"kind": "op", "doc": "d", "client": 1,
                     "clientSeq": 1, "refSeq": 0, "contents": {}})
         role.step()
+        role.wait_checkpoint()
         before = writes.value
     if role._ckpt_dirty:
         time.sleep(0.06)
         role.step(idle_sleep=0.0)  # no new input: the idle branch
+        role.wait_checkpoint()
         assert writes.value >= before + 1
     assert not role._ckpt_dirty
 

@@ -204,8 +204,8 @@ def test_kernel_deli_step_and_checkpoint_span_trees(tmp_path, fresh):
     assert root[3] is None
     # The snapshot's second span frees it, after the write.
     assert _tree(spans, root[4]) == [
-        (1, "deli.checkpoint.snapshot"), (1, "checkpoint.fence"),
-        (1, "checkpoint.encode"), (1, "checkpoint.write"),
+        (1, "deli.checkpoint.snapshot"), (1, "checkpoint.encode"),
+        (1, "checkpoint.fence"), (1, "checkpoint.write"),
         (1, "deli.checkpoint.snapshot"),
     ]
     # The phases cover the checkpoint, and its histogram times it.
